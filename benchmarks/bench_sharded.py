@@ -15,7 +15,9 @@ compiled collective by the mesh axes it crosses
 
 Each device count needs its own XLA device set, so the parent re-execs
 itself as a `--child` subprocess with
-`XLA_FLAGS=--xla_force_host_platform_device_count=N` before jax init.
+`XLA_FLAGS=--xla_force_host_platform_device_count=N` before jax init. That
+flag only shapes the CPU backend: on an accelerator the child refuses to
+run, and the parent raises instead of recording a result.
 Results land in ``benchmarks/BENCH_sharded.json`` (folded into
 ``BENCH_summary.json`` by ``benchmarks/run.py``).
 
@@ -53,11 +55,19 @@ def _child(devices: int, quick: bool) -> dict:
                                            filter_model_norm_rows,
                                            summarize_axis_rows)
     from repro.launch.inputs import concrete_train_batch
+    from repro.launch.mesh import make_debug_mesh
     from repro.models.transformer import build_model
 
-    assert jax.device_count() == devices, (jax.device_count(), devices)
+    if jax.default_backend() != "cpu":
+        raise SystemExit(
+            f"bench_sharded measures virtual CPU devices; JAX found "
+            f"{jax.default_backend()!r}, where "
+            "--xla_force_host_platform_device_count cannot set the count")
+    if jax.device_count() != devices:
+        raise SystemExit(f"want {devices} devices, JAX sees "
+                         f"{jax.device_count()}")
     d, m = MESHES[devices]
-    mesh = jax.make_mesh((d, m), ("data", "model"))
+    mesh = make_debug_mesh(d, m)
     cfg = get_config("tiny")
     model = build_model(cfg)
     params = init_params(model.spec, jax.random.PRNGKey(0))
@@ -111,10 +121,9 @@ def run(quick: bool = True, device_counts=(1, 4, 8)) -> list[str]:
                              timeout=1800)
         mm = re.search(r"CHILD_RESULT (.*)", out.stdout)
         if out.returncode != 0 or not mm:
-            lines.append(csv_line(f"sharded_{n}dev_ERROR", 0.0,
-                                  out.stderr.strip()[-200:].replace(",", ";")
-                                  or "no output"))
-            continue
+            raise RuntimeError(f"bench_sharded child for {n} devices failed "
+                               f"(exit {out.returncode}):\n"
+                               f"{out.stderr.strip()[-2000:]}")
         payload = json.loads(mm.group(1))
         runs[str(n)] = payload
         for r in payload["records"]:

@@ -13,6 +13,10 @@ topology stamp, folded into ``BENCH_summary.json`` by ``benchmarks/run``.
 ``python -m benchmarks.bench_startup --smoke`` ASSERTS the acceptance bar:
 warm-start wall time strictly below cold-start for BOTH entry points
 (scripts/bench_smoke.sh and CI run this).
+
+The parent never imports JAX: on an accelerator a process that has touched
+JAX holds the device, and the entry points it starts need it. The topology
+stamp comes from a child too (`python -m repro.kernels.autotune --show`).
 """
 from __future__ import annotations
 
@@ -25,7 +29,7 @@ import sys
 import tempfile
 import time
 
-from benchmarks.common import csv_line, topology
+from benchmarks.common import csv_line
 
 _OUT_PATH = os.path.join(os.path.dirname(__file__), "BENCH_startup.json")
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -42,15 +46,28 @@ ENTRIES = {
 }
 
 
-def _run_cli(argv: list[str], cache_root: str) -> float:
+def _child_env() -> dict:
     env = dict(os.environ)
     env["PYTHONPATH"] = ("src" + os.pathsep + env["PYTHONPATH"]
                          if env.get("PYTHONPATH") else "src")
+    return env
+
+
+def _run_cli(argv: list[str], cache_root: str) -> float:
     t0 = time.perf_counter()
     subprocess.run([sys.executable, *argv, "--cache-dir", cache_root],
-                   cwd=_REPO, env=env, check=True,
+                   cwd=_REPO, env=_child_env(), check=True,
                    stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL)
     return time.perf_counter() - t0
+
+
+def _topology() -> dict:
+    """The device stamp, read in a child process (see module doc)."""
+    out = subprocess.run(
+        [sys.executable, "-m", "repro.kernels.autotune", "--show"],
+        cwd=_REPO, env=_child_env(), check=True, capture_output=True,
+        text=True)
+    return json.loads(out.stdout)["topology"]
 
 
 def run(quick: bool = True) -> list[str]:
@@ -78,7 +95,7 @@ def run(quick: bool = True) -> list[str]:
     finally:
         shutil.rmtree(root, ignore_errors=True)
 
-    payload = {"topology": topology(), "unix_time": int(time.time()),
+    payload = {"topology": _topology(), "unix_time": int(time.time()),
                "records": records}
     with open(_OUT_PATH, "w") as fh:
         json.dump(payload, fh, indent=1)

@@ -1,14 +1,12 @@
-"""Shared benchmark harness utilities."""
+"""Shared benchmark harness utilities.
+
+JAX is imported inside the functions that use it: suites whose parent only
+starts worker processes (`bench_startup`, `bench_sharded`) import this module
+without touching JAX, so the workers are the only processes on the device.
+"""
 from __future__ import annotations
 
 import time
-
-import jax
-import jax.numpy as jnp
-import numpy as np
-
-from repro.core import dp_layers as dpl
-from repro.core.spec import GroupLayout, P, init_params
 
 
 def topology() -> dict:
@@ -22,6 +20,8 @@ def topology() -> dict:
 
 def timeit(fn, *args, warmup: int = 2, iters: int = 5) -> float:
     """Median wall time per call in microseconds (blocks on results)."""
+    import jax
+    import numpy as np
     for _ in range(warmup):
         jax.block_until_ready(fn(*args))
     ts = []
@@ -48,6 +48,11 @@ def mlp_classifier(dim: int, width: int, depth: int, classes: int,
     feature_scales: optional per-layer input magnification — creates the
     strongly NON-uniform per-layer gradient norms of the paper's Figure 2
     (what makes hand-set uniform per-layer thresholds hurt)."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import dp_layers as dpl
+    from repro.core.spec import GroupLayout, P
     spec = {}
     sizes = [dim] + [width] * depth + [classes]
     for i, (a, b) in enumerate(zip(sizes[:-1], sizes[1:])):

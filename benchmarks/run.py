@@ -19,6 +19,11 @@ into ``BENCH_summary.json`` so the perf trajectory across PRs is
 machine-readable from ONE file (``--aggregate-only`` refreshes it without
 re-benchmarking).
 
+Each suite runs in a process of its own, one after another: on an
+accelerator a process that has touched JAX holds the device, so a runner
+that ran suites in-process would lock out the workers that `startup`,
+`sharded` and `scaling` start.
+
 Run:  PYTHONPATH=src python -m benchmarks.run [--full] [--only PREFIX]
                                               [--aggregate-only]
 """
@@ -28,6 +33,7 @@ import argparse
 import glob
 import json
 import os
+import subprocess
 import sys
 import time
 
@@ -53,6 +59,21 @@ def aggregate() -> str:
     return _SUMMARY_PATH
 
 
+# suite name -> module under benchmarks/
+SUITES = {
+    "throughput": "bench_throughput",
+    "kernels": "bench_kernels",
+    "startup": "bench_startup",
+    "sharded": "bench_sharded",
+    "serve": "bench_serve",
+    "utility": "bench_utility",
+    "epochs": "bench_epochs",
+    "quantile": "bench_quantile",
+    "scaling": "bench_scaling",
+    "roofline": "roofline",
+}
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true",
@@ -68,35 +89,20 @@ def main() -> None:
         print(f"# wrote {aggregate()}", file=sys.stderr)
         return
 
-    from benchmarks import (bench_epochs, bench_kernels, bench_quantile,
-                            bench_scaling, bench_serve, bench_sharded,
-                            bench_startup, bench_throughput, bench_utility,
-                            roofline)
-    suites = [
-        ("throughput", bench_throughput),
-        ("kernels", bench_kernels),
-        ("startup", bench_startup),
-        ("sharded", bench_sharded),
-        ("serve", bench_serve),
-        ("utility", bench_utility),
-        ("epochs", bench_epochs),
-        ("quantile", bench_quantile),
-        ("scaling", bench_scaling),
-        ("roofline", roofline),
-    ]
-    print("name,us_per_call,derived")
+    print("name,us_per_call,derived", flush=True)
     failures = 0
-    for name, mod in suites:
+    for name, module in SUITES.items():
         if args.only and args.only not in name:
             continue
         t0 = time.time()
-        try:
-            for line in mod.run(quick=quick):
-                print(line, flush=True)
-        except Exception as e:  # noqa: BLE001
+        code = (f"from benchmarks.{module} import run\n"
+                f"for line in run(quick={quick}):\n"
+                f"    print(line, flush=True)\n")
+        rc = subprocess.run([sys.executable, "-c", code],
+                            cwd=os.path.dirname(_BENCH_DIR)).returncode
+        if rc:
             failures += 1
-            print(f"{name}_SUITE_ERROR,0,{type(e).__name__}:{e}",
-                  flush=True)
+            print(f"{name}_SUITE_ERROR,0,exit {rc}", flush=True)
         print(f"# suite {name} took {time.time()-t0:.1f}s", file=sys.stderr)
     print(f"# wrote {aggregate()}", file=sys.stderr)
     if failures:

@@ -18,5 +18,4 @@ if __name__ == "__main__":
                 "--checkpoint-dir", "/tmp/repro_e2e_ckpt",
                 "--log-every", "20"]
     # user args win
-    sys.argv = [sys.argv[0]] + defaults + argv
-    raise SystemExit(main())
+    raise SystemExit(main(defaults + argv))

@@ -323,13 +323,31 @@ def _comp_has(an: HloAnalyzer, comp: str, pred, memo: dict) -> bool:
 
 
 _TRANSPOSED = re.compile(r'op_name="[^"]*transpose\(jvp')
+_S32_CONST = re.compile(r"^\s*(-?\d+)\)")
+
+
+def _trip_count(an: HloAnalyzer, ins: Instr) -> int | None:
+    """Iterations of a `while`: its known_trip_count annotation, else the
+    one s32 scalar constant its condition compares the counter against
+    (the TPU compiler rewrites layer loops, e.g. into double-buffered
+    `wide.*` loops, and drops the annotation)."""
+    t = _TRIP.search(ins.rest)
+    if t:
+        return int(t.group(1))
+    cm = re.search(r"condition=%?([\w.\-]+)", ins.rest)
+    if not cm:
+        return None
+    bounds = {int(m.group(1)) for c in an.comps.get(cm.group(1), [])
+              if c.op == "constant" and c.shape.startswith("s32[]")
+              for m in [_S32_CONST.match(c.rest)] if m}
+    return bounds.pop() if len(bounds) == 1 else None
 
 
 def _layer_loops(text: str, trip: int) -> tuple[int, int]:
     """(forward, backward) counts of innermost dot-bearing layer loops.
 
-    A scanned layer stack of depth L lowers to one `while` with
-    known_trip_count == L per traversal direction. Direction comes from
+    A scanned layer stack of depth L lowers to one `while` of L iterations
+    per traversal direction (`_trip_count`). Direction comes from
     JAX's op_name metadata: the transposed (reverse) scan of a backward
     pass tags its body `transpose(jvp(while))/...`, the forward scan
     `jvp(while)`/`while`. Outer loops that merely CONTAIN trip-matching
@@ -343,13 +361,11 @@ def _layer_loops(text: str, trip: int) -> tuple[int, int]:
     has_transpose: dict = {}
 
     def is_dot(ins):
-        return ins.op in ("dot", "dot-general")
+        # the TPU compiler emits matmuls as `convolution`
+        return ins.op in ("dot", "dot-general", "convolution")
 
     def is_trip_while(ins):
-        if ins.op != "while":
-            return False
-        t = _TRIP.search(ins.rest)
-        return bool(t) and int(t.group(1)) == trip
+        return ins.op == "while" and _trip_count(an, ins) == trip
 
     def is_transposed(ins):
         return bool(_TRANSPOSED.search(ins.rest))

@@ -48,13 +48,9 @@ import re
 from typing import Any, Callable
 
 import jax
+from jax.extend.core import Literal as _Literal
 
 from repro.analysis.findings import ERROR, WARNING, Finding
-
-try:  # jax >= 0.5 moved core off the public root
-    from jax.core import Literal as _Literal
-except Exception:  # noqa: BLE001
-    from jax._src.core import Literal as _Literal
 
 CLIP_SCOPE = "dp_clip_factor"
 NOISE_SCOPE = "dp_noise_add:"

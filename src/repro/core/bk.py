@@ -37,6 +37,7 @@ Pipeline (driven by `core.clipping.dp_clipped_gradients`):
                          once per step (e.g. the MTP head) or shared-site
                          parameters (sensitivity_mult > 1), whose single
                          threshold leaf would sum residuals across sites.
+                         Any other probe failure raises.
   2. `capture_clipped` — ONE `value_and_grad` over the channel tree:
                          per-group norms² + cached residuals.
   3. driver computes the per-example clip factors from the norms.
@@ -54,7 +55,6 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import dataclasses
-import warnings
 from typing import Any
 
 import jax
@@ -270,20 +270,12 @@ def probe_recipes(loss_fn, params, batch, layout: GroupLayout,
         return None
     inf_tree = layout.pack_value(jnp.inf, batch_size)
     probe = {k: BkChannel(v, None, k) for k, v in inf_tree.items()}
-    try:
-        with _recording() as rec:
-            jax.eval_shape(lambda p, b, t: jnp.sum(loss_fn(p, b, t)),
-                           params, batch, probe)
-    except Exception as e:  # noqa: BLE001 — probe failure -> twopass, but
-        # LOUDLY: a loss that cannot trace with channel leaves is either a
-        # model manipulating thresholds as raw arrays (legitimately not
-        # BK-able) or a bug in a record_* recorder; silent fallback would
-        # double the step time with nothing to distinguish the two.
-        warnings.warn(
-            f"BK shape probe failed ({type(e).__name__}: {e}); falling "
-            "back to the twopass execution for this clipping driver",
-            stacklevel=2)
-        return None
+    # any other failure to trace with channel leaves is a bug (in a model
+    # that handles thresholds as raw arrays, or in a record_* recorder):
+    # it raises rather than silently doubling the backward passes
+    with _recording() as rec:
+        jax.eval_shape(lambda p, b, t: jnp.sum(loss_fn(p, b, t)),
+                       params, batch, probe)
     if any(r.count > 1 for r in rec.values()):
         return None  # one leaf, several call sites (e.g. MTP reuses head)
     return rec

@@ -151,13 +151,8 @@ def make_pipeline_loss(cfg: PipelineConfig, mesh, *, stage_axis: str = "pod"):
 
     # shard_map: blocks sharded on stage, inputs/outputs replicated
     _in_specs = (PS(stage_axis), PS(stage_axis), PS(), PS(stage_axis))
-    if hasattr(jax, "shard_map"):
-        smapped = jax.shard_map(pipelined, mesh=mesh, in_specs=_in_specs,
-                                out_specs=PS(), check_vma=False)
-    else:  # jax<=0.4: experimental API, replication check is `check_rep`
-        from jax.experimental.shard_map import shard_map as _shard_map
-        smapped = _shard_map(pipelined, mesh=mesh, in_specs=_in_specs,
-                             out_specs=PS(), check_rep=False)
+    smapped = jax.shard_map(pipelined, mesh=mesh, in_specs=_in_specs,
+                            out_specs=PS(), check_vma=False)
 
     def loss_fn(params, batch, th, *, n_micro: int = 2):
         x, y = batch  # (B, d_in), (B,)

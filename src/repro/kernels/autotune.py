@@ -65,10 +65,9 @@ _BACKEND_CHOICES = ("xla", "pallas")
 
 
 def device_kind() -> str:
-    try:
-        return jax.devices()[0].device_kind
-    except Exception:  # noqa: BLE001 - no devices: stamp still well-formed
-        return "unknown"
+    """The device kind measurements are conditioned on. Raises when JAX
+    finds no device: a stamp must name the hardware it describes."""
+    return jax.devices()[0].device_kind
 
 
 def topology_stamp() -> dict:
@@ -383,18 +382,16 @@ def _op_fn(engine, op: str, shape):
 
 def measure_op(op: str, shape, *, backends=_BACKEND_CHOICES,
                warmup: int = 2, iters: int = 5) -> dict[str, float]:
-    """Median wall µs per backend for one (op, shape). Backends whose run
-    fails (e.g. a kernel that cannot lower here) are skipped, not fatal."""
+    """Median wall µs per backend for one (op, shape). A backend that fails
+    to compile or run raises: a sweep that silently dropped it would hand
+    `auto` a table in which the other backend always wins."""
     from repro.kernels import backend as KB
     args = _op_data(op, shape)
     out: dict[str, float] = {}
     for name in backends:
         eng = KB.make_engine(name)
-        try:
-            out[name] = _median_us(_op_fn(eng, op, shape), args,
-                                   warmup=warmup, iters=iters)
-        except Exception:  # noqa: BLE001 - unmeasurable backend: no entry
-            continue
+        out[name] = _median_us(_op_fn(eng, op, shape), args,
+                               warmup=warmup, iters=iters)
     return out
 
 

@@ -82,7 +82,7 @@ from repro.kernels.ref import paged_attn_ref
 __all__ = [
     "EngineConfig", "Backend", "XlaBackend", "PallasBackend", "AutoBackend",
     "register_backend", "backends", "make_engine", "active", "scoped",
-    "clip_factor", "choose_linear_path", "choose_op",
+    "clip_factor", "choose_linear_path", "choose_op", "recording_choices",
 ]
 
 
@@ -381,8 +381,17 @@ def choose_op(op: str, t: int, din: int, dout: int, config: EngineConfig,
 
     op is one of `autotune.OPS`; `table=None` consults the installed table
     (`autotune.installed_table()`), which entry points install under their
-    --autotune knob and tests scope with `autotune.use_table`.
+    --autotune knob and tests scope with `autotune.use_table`. Every
+    decision lands in the enclosing `recording_choices()` log, if any.
     """
+    choice = _decide(op, t, din, dout, config, on_tpu=on_tpu, table=table)
+    log = _CHOICES.get()
+    if log is not None:
+        log[(op, int(t), int(din), int(dout))] = choice
+    return choice
+
+
+def _decide(op, t, din, dout, config, *, on_tpu, table) -> str:
     if config.autotune:
         tab = table if table is not None else autotune.installed_table()
         if tab is not None:
@@ -396,6 +405,26 @@ def choose_op(op: str, t: int, din: int, dout: int, config: EngineConfig,
             on_tpu = jax.default_backend() == "tpu"
         return "pallas" if (on_tpu or config.interpret is True) else "xla"
     return choose_linear_path(t, din, dout, config, on_tpu=on_tpu)
+
+
+_CHOICES: contextvars.ContextVar[dict | None] = contextvars.ContextVar(
+    "ghost_op_choices", default=None)
+
+
+@contextlib.contextmanager
+def recording_choices():
+    """Log the `auto` decisions traced inside the block.
+
+    Yields a dict filled at TRACE time with {(op, t, din, dout): 'xla' |
+    'pallas'}; a program traced in the block (a persistent-cache hit still
+    traces) reports which implementation each ghost op resolved to.
+    """
+    log: dict = {}
+    token = _CHOICES.set(log)
+    try:
+        yield log
+    finally:
+        _CHOICES.reset(token)
 
 
 @register_backend("auto")

@@ -52,7 +52,7 @@ def _kernel(a_ref, g_ref, f_ref, out_ref, acc, *, nr):
 
 def scale_contract(a: jax.Array, g: jax.Array, factors: jax.Array, *,
                    bi: int = DEFAULT_BI, bj: int = DEFAULT_BJ,
-                   bt: int = DEFAULT_BT, interpret: bool = True) -> jax.Array:
+                   bt: int = DEFAULT_BT, interpret: bool = False) -> jax.Array:
     """(S, din, dout) = Σ_i f[s,i] A[s,i]ᵀ G[s,i] from cached BK residuals.
 
     a: (S, B, T, din) or (B, T, din); g: same leading shape with dout;
@@ -90,6 +90,7 @@ def scale_contract(a: jax.Array, g: jax.Array, factors: jax.Array, *,
         out_shape=jax.ShapeDtypeStruct((s, dip, djp), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bi, bj), jnp.float32)],
         interpret=interpret,
+        name="bk_scale_contract",
     )(a2, g2, f2)
     out = out[:, :din, :dout]
     return out[0] if squeeze else out

@@ -46,7 +46,7 @@ def _kernel(a_ref, g_ref, c_ref, out_ref, acc, *, nr):
 
 def clip_reduce(a: jax.Array, g: jax.Array, factors: jax.Array, *,
                 bi: int = DEFAULT_BI, bj: int = DEFAULT_BJ,
-                bt: int = DEFAULT_BT, interpret: bool = True) -> jax.Array:
+                bt: int = DEFAULT_BT, interpret: bool = False) -> jax.Array:
     """(din, dout) = Σ_i c_i A_iᵀ G_i.  a: (B,T,din); g: (B,T,dout);
     factors: (B,)."""
     b, t, din = a.shape
@@ -78,5 +78,6 @@ def clip_reduce(a: jax.Array, g: jax.Array, factors: jax.Array, *,
         out_shape=jax.ShapeDtypeStruct((dip, djp), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bi, bj), jnp.float32)],
         interpret=interpret,
+        name="clip_reduce",
     )(a2, g2, c2)
     return out[:din, :dout]

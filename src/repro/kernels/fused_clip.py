@@ -13,7 +13,7 @@ co-grouped parameters (the bias of the layer) so the factor matches the
 whole clipping group.
 
 Grid = (B, T/bt, T/bt), b outermost, sequentially executed:
-  * (i, j) with j >= i accumulate the gram contraction into an SMEM norm
+  * (i, j) with j >= i accumulate the gram contraction into a VMEM norm
     accumulator (off-diagonal doubled — symmetry, as in `ghost_norm`);
   * diagonal steps (i == j) also accumulate A_iᵀ G_i into a VMEM dW
     accumulator — the unscaled per-example grad, built from blocks already
@@ -36,6 +36,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BT = 256
+# Per-example scalars (threshold, extra norm², norm²) travel broadcast over
+# one f32 (8, 128) vreg tile: Mosaic needs the last two block dims aligned
+# to (8, 128) or equal to the array's, so (1, 1) blocks cannot compile.
+TILE = (8, 128)
 
 
 def padded_dims(din: int, dout: int) -> tuple[int, int]:
@@ -58,7 +62,7 @@ def _kernel(a_i, a_j, g_i, g_j, c_ref, e_ref, n_out, dw_out, n_acc, dw_acc,
 
     @pl.when((i == 0) & (j == 0))
     def _init():
-        n_acc[0, 0] = 0.0
+        n_acc[...] = jnp.zeros_like(n_acc)
         dw_acc[...] = jnp.zeros_like(dw_acc)
 
     @pl.when(upper)
@@ -69,8 +73,8 @@ def _kernel(a_i, a_j, g_i, g_j, c_ref, e_ref, n_out, dw_out, n_acc, dw_acc,
         gram_g = jax.lax.dot_general(
             g_i[0].astype(jnp.float32), g_j[0].astype(jnp.float32),
             (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-        n_acc[0, 0] += (jnp.sum(gram_a * gram_g)
-                        * jnp.where(i == j, 1.0, 2.0))
+        n_acc[...] += (jnp.sum(gram_a * gram_g)
+                       * jnp.where(i == j, 1.0, 2.0))
 
     @pl.when(i == j)
     def _grad():
@@ -82,18 +86,19 @@ def _kernel(a_i, a_j, g_i, g_j, c_ref, e_ref, n_out, dw_out, n_acc, dw_acc,
     def _emit():
         # lazy import: core.__init__ transitively imports this module, so a
         # top-level import would see it partially initialized. The shared
-        # encoded-threshold helper is plain jnp and runs on the VPU.
+        # encoded-threshold helper is plain jnp and runs on the VPU over the
+        # broadcast (8, 128) tiles; every lane holds the same value.
         from repro.core.ghost import clip_factor
-        n = n_acc[0, 0]
-        n_out[0, 0] = n
-        f = clip_factor(c_ref[0, 0], n + e_ref[0, 0])
+        n = n_acc[...]
+        n_out[0] = n
+        f = jnp.max(clip_factor(c_ref[0], n + e_ref[0]))
         scaled = f * dw_acc[...]
         dw_out[...] = jnp.where(b == 0, scaled, dw_out[...] + scaled)
 
 
 def fused_norm_clip(a: jax.Array, g: jax.Array, c: jax.Array,
                     extra_norms_sq: jax.Array | None = None, *,
-                    bt: int = DEFAULT_BT, interpret: bool = True
+                    bt: int = DEFAULT_BT, interpret: bool = False
                     ) -> tuple[jax.Array, jax.Array]:
     """Returns (norms_sq (B,), clipped summed grad (din, dout) f32).
 
@@ -111,9 +116,10 @@ def fused_norm_clip(a: jax.Array, g: jax.Array, c: jax.Array,
     dip, djp = padded_dims(din, dout)
     a_p = jnp.pad(a, ((0, 0), (0, tp - t), (0, dip - din)))
     g_p = jnp.pad(g, ((0, 0), (0, tp - t), (0, djp - dout)))
-    c2 = c.reshape(b, 1).astype(jnp.float32)
-    e2 = (jnp.zeros((b, 1), jnp.float32) if extra_norms_sq is None
-          else extra_norms_sq.reshape(b, 1).astype(jnp.float32))
+    c2 = jnp.broadcast_to(c.astype(jnp.float32)[:, None, None], (b,) + TILE)
+    e2 = (jnp.zeros((b,) + TILE, jnp.float32) if extra_norms_sq is None
+          else jnp.broadcast_to(
+              extra_norms_sq.astype(jnp.float32)[:, None, None], (b,) + TILE))
     nt = tp // bt
 
     grid = (b, nt, nt)
@@ -125,21 +131,22 @@ def fused_norm_clip(a: jax.Array, g: jax.Array, c: jax.Array,
             pl.BlockSpec((1, bt, dip), lambda bb, i, j: (bb, j, 0)),
             pl.BlockSpec((1, bt, djp), lambda bb, i, j: (bb, i, 0)),
             pl.BlockSpec((1, bt, djp), lambda bb, i, j: (bb, j, 0)),
-            pl.BlockSpec((1, 1), lambda bb, i, j: (bb, 0)),
-            pl.BlockSpec((1, 1), lambda bb, i, j: (bb, 0)),
+            pl.BlockSpec((1,) + TILE, lambda bb, i, j: (bb, 0, 0)),
+            pl.BlockSpec((1,) + TILE, lambda bb, i, j: (bb, 0, 0)),
         ],
         out_specs=(
-            pl.BlockSpec((1, 1), lambda bb, i, j: (bb, 0)),
+            pl.BlockSpec((1,) + TILE, lambda bb, i, j: (bb, 0, 0)),
             pl.BlockSpec((dip, djp), lambda bb, i, j: (0, 0)),
         ),
         out_shape=(
-            jax.ShapeDtypeStruct((b, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b,) + TILE, jnp.float32),
             jax.ShapeDtypeStruct((dip, djp), jnp.float32),
         ),
         scratch_shapes=[
-            pltpu.SMEM((1, 1), jnp.float32),      # per-example norm² acc
+            pltpu.VMEM(TILE, jnp.float32),        # per-example norm² acc
             pltpu.VMEM((dip, djp), jnp.float32),  # per-example grad acc
         ],
         interpret=interpret,
+        name="fused_norm_clip",
     )(a_p, a_p, g_p, g_p, c2, e2)
-    return norms[:, 0], dw[:din, :dout]
+    return norms[:, 0, 0], dw[:din, :dout]

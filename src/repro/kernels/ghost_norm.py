@@ -35,6 +35,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BT = 256  # sequence tile
 DEFAULT_DK = 512  # feature-chunk tile
+# Each example's norm² is written broadcast over one f32 (8, 128) vreg tile:
+# Mosaic needs the last two block dims aligned to (8, 128) or equal to the
+# array's, so a (1, 1) block over a (B, 1) output cannot compile for TPU.
+OUT_TILE = (8, 128)
 
 
 def _kernel(a_i, a_j, g_i, g_j, out_ref, acc_a, acc_g, *, nda, ndg, nk):
@@ -71,15 +75,15 @@ def _kernel(a_i, a_j, g_i, g_j, out_ref, acc_a, acc_g, *, nda, ndg, nk):
                * jnp.where(i == j, 1.0, 2.0)
                * jnp.where(upper, 1.0, 0.0))
         first = (i == 0) & (j == 0)
-        out_ref[0, 0] = jnp.where(first, val, out_ref[0, 0] + val)
+        out_ref[...] = jnp.where(first, val, out_ref[...] + val)
 
 
 def ghost_norm(a: jax.Array, g: jax.Array, *, bt: int = DEFAULT_BT,
-               dk: int = DEFAULT_DK, interpret: bool = True) -> jax.Array:
+               dk: int = DEFAULT_DK, interpret: bool = False) -> jax.Array:
     """(B,) squared per-example grad norms. a: (B,T,din); g: (B,T,dout).
 
-    interpret=True executes the kernel body on CPU (validation mode);
-    on TPU pass interpret=False.
+    interpret=True executes the kernel body on the host (validation mode
+    off-TPU); the default compiles it through Mosaic.
     """
     b, t, din = a.shape
     dout = g.shape[-1]
@@ -106,16 +110,18 @@ def ghost_norm(a: jax.Array, g: jax.Array, *, bt: int = DEFAULT_BT,
             pl.BlockSpec((1, bt, dkg), lambda bb, i, j, k: (bb, i, jnp.minimum(k, ndg - 1))),
             pl.BlockSpec((1, bt, dkg), lambda bb, i, j, k: (bb, j, jnp.minimum(k, ndg - 1))),
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda bb, i, j, k: (bb, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, 1), jnp.float32),
+        out_specs=pl.BlockSpec((1,) + OUT_TILE,
+                               lambda bb, i, j, k: (bb, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((b,) + OUT_TILE, jnp.float32),
         scratch_shapes=[
             # two gram-block accumulators held in VMEM across the k loop
             pltpu.VMEM((bt, bt), jnp.float32),
             pltpu.VMEM((bt, bt), jnp.float32),
         ],
         interpret=interpret,
+        name="ghost_norm",
     )(a_p, a_p, g_p, g_p)
-    return out[:, 0]
+    return out[:, 0, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -153,12 +159,12 @@ def _blocked_kernel(s_i, s_j, x_i, x_j, out_ref, acc_s, acc_x, *,
                * jnp.where(i == j, 1.0, 2.0)
                * jnp.where(upper, 1.0, 0.0))
         first = (i == 0) & (j == 0)
-        out_ref[0, 0] = jnp.where(first, val, out_ref[0, 0] + val)
+        out_ref[...] = jnp.where(first, val, out_ref[...] + val)
 
 
 def ghost_norm_blocked(a: jax.Array, g: jax.Array, num_blocks: int, *,
                        block_axis: str = "out", bt: int = DEFAULT_BT,
-                       dk: int = DEFAULT_DK, interpret: bool = True
+                       dk: int = DEFAULT_DK, interpret: bool = False
                        ) -> jax.Array:
     """(B, M) squared per-example norms of M weight blocks — the per-shard
     (per-device) clipping hot path. a: (B, T, din); g: (B, T, dout).
@@ -219,12 +225,14 @@ def ghost_norm_blocked(a: jax.Array, g: jax.Array, num_blocks: int, *,
             pl.BlockSpec((1, 1, bt, dkx),
                          lambda bb, mm, i, j, k: (bb, mm, j, jnp.minimum(k, ndx - 1))),
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda bb, mm, i, j, k: (bb, mm)),
-        out_shape=jax.ShapeDtypeStruct((b, m), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1) + OUT_TILE,
+                               lambda bb, mm, i, j, k: (bb, mm, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, m) + OUT_TILE, jnp.float32),
         scratch_shapes=[
             pltpu.VMEM((bt, bt), jnp.float32),
             pltpu.VMEM((bt, bt), jnp.float32),
         ],
         interpret=interpret,
+        name="ghost_norm_blocked",
     )(s_p, s_p, x_p, x_p)
-    return out
+    return out[:, :, 0, 0]

@@ -24,43 +24,48 @@ from repro.kernels.fused_clip import fused_norm_clip
 from repro.kernels.ghost_norm import ghost_norm, ghost_norm_blocked
 from repro.kernels.paged_attn import paged_attn
 
-_INTERPRET = jax.default_backend() != "tpu"
+
+def _interpret() -> bool:
+    """Interpret off-TPU. Asked at trace time, not import time: importing
+    this module must not initialize a JAX backend (that would claim the
+    chip in a parent process that only spawns workers)."""
+    return jax.default_backend() != "tpu"
 
 
 @partial(jax.jit, static_argnames=("bt", "dk"))
 def ghost_norm_op(a, g, *, bt: int = 256, dk: int = 512):
-    return ghost_norm(a, g, bt=bt, dk=dk, interpret=_INTERPRET)
+    return ghost_norm(a, g, bt=bt, dk=dk, interpret=_interpret())
 
 
 @partial(jax.jit, static_argnames=("num_blocks", "block_axis", "bt", "dk"))
 def ghost_norm_blocked_op(a, g, num_blocks: int, *, block_axis: str = "out",
                           bt: int = 256, dk: int = 512):
     return ghost_norm_blocked(a, g, num_blocks, block_axis=block_axis,
-                              bt=bt, dk=dk, interpret=_INTERPRET)
+                              bt=bt, dk=dk, interpret=_interpret())
 
 
 @partial(jax.jit, static_argnames=("bi", "bj", "bt"))
 def clip_reduce_op(a, g, factors, *, bi: int = 256, bj: int = 256,
                    bt: int = 256):
     return clip_reduce(a, g, factors, bi=bi, bj=bj, bt=bt,
-                       interpret=_INTERPRET)
+                       interpret=_interpret())
 
 
 @partial(jax.jit, static_argnames=("bt",))
 def fused_norm_clip_op(a, g, c, extra_norms_sq=None, *, bt: int = 256):
     return fused_norm_clip(a, g, c, extra_norms_sq, bt=bt,
-                           interpret=_INTERPRET)
+                           interpret=_interpret())
 
 
 @partial(jax.jit, static_argnames=("bi", "bj", "bt"))
 def scale_contract_op(a, g, factors, *, bi: int = 256, bj: int = 256,
                       bt: int = 256):
     return scale_contract(a, g, factors, bi=bi, bj=bj, bt=bt,
-                          interpret=_INTERPRET)
+                          interpret=_interpret())
 
 
 @partial(jax.jit, static_argnames=("scale", "dv"))
 def paged_attn_op(q, kpool, vpool, pt, pos, *, scale: float,
                   dv: int | None = None):
     return paged_attn(q, kpool, vpool, pt, pos, scale=scale, dv=dv,
-                      interpret=_INTERPRET)
+                      interpret=_interpret())

@@ -116,4 +116,5 @@ def paged_attn(q, kpool, vpool, pt, pos, *, scale: float,
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kv, g, dv), jnp.float32),
         interpret=interpret,
+        name="paged_attn",
     )(pt.astype(jnp.int32), pos.astype(jnp.int32), q, kpool, vpool)

@@ -28,10 +28,18 @@ never a stale hit. On top of that this module adds:
     actually covered (`warmed_programs()`).
 
 Entry points call `enable()` under their ``--cache`` knob (train, serve,
-service, dryrun) and `record_program()` after building their step; the
-cache directory defaults to ``<repo>/.cache/compile`` (``REPRO_CACHE_DIR``
-or ``--cache-dir`` override). Everything here is best-effort: cache
-trouble degrades to cold compiles, never to a crashed worker.
+service, dryrun) and `record_program()` after building their step. The
+directory rule:
+
+  * ``JAX_COMPILATION_CACHE_DIR`` set: that directory is the cache, as JAX
+    itself reads it. It belongs to whoever set it, so it is never swept
+    and nothing in it is deleted.
+  * unset: ``<repo>/.cache/compile`` (``REPRO_CACHE_DIR`` or
+    ``--cache-dir`` move the cache root), swept as above. The path is
+    fixed so that every run of the checkout finds the same entries.
+
+A cache root that cannot be used is reported with a warning and the run
+compiles uncached.
 """
 from __future__ import annotations
 
@@ -54,8 +62,14 @@ def cache_root(override: str | None = None) -> str:
     return repo_cache_root(override)
 
 
+def external_dir() -> str | None:
+    """The cache directory placed from outside (JAX_COMPILATION_CACHE_DIR),
+    or None."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or None
+
+
 def compile_dir(root: str | None = None) -> str:
-    return os.path.join(cache_root(root), "compile")
+    return external_dir() or os.path.join(cache_root(root), "compile")
 
 
 def program_key(**parts) -> str:
@@ -88,16 +102,14 @@ def _entry_decodes(path: str) -> bool:
     (heap corruption, not a catchable error), so a torn entry must never
     be adopted into the manifest. The compression checksum (zstd frame /
     zlib adler32) reliably rejects any truncation."""
+    from jax._src import compilation_cache as jcc
     try:
         with open(path, "rb") as fh:
             raw = fh.read()
     except OSError:
         return False
     try:
-        from jax._src import compilation_cache as jcc
         jcc.extract_executable_and_time(jcc.decompress_executable(raw))
-        return True
-    except ImportError:  # internals moved: cannot validate, keep the entry
         return True
     except Exception:  # noqa: BLE001 - torn/garbage payload
         return False
@@ -231,36 +243,35 @@ def sweep(dirpath: str) -> dict:
 # ---------------------------------------------------------------------------
 
 
-def enable(root: str | None = None, *, min_compile_secs: float = 0.0,
-           quiet: bool = True) -> str | None:
-    """Sweep + point jax's persistent compilation cache at the repo-local
-    dir. Idempotent; best-effort (returns None and leaves compilation
-    uncached on any failure — a worker never dies over cache trouble)."""
+def enable(root: str | None = None, *,
+           min_compile_secs: float = 0.0) -> str | None:
+    """Point jax's persistent compilation cache at the directory the rule in
+    the module doc gives, sweeping it first unless it was placed from
+    outside. Idempotent. Returns the directory, or None (with a warning)
+    when it cannot be used — compilation then stays uncached."""
     global _ENABLED_DIR
-    try:
-        dirpath = compile_dir(root)
-        sweep(dirpath)
-        jax.config.update("jax_compilation_cache_dir", dirpath)
-        # jax memoizes "is the cache used" at the FIRST compilation of the
-        # process; a long-lived process (tests, notebooks) that compiled
-        # anything before enable() has latched False — reset to pristine so
-        # the new directory takes effect
-        _reset_jax_cache_state()
-        # default thresholds skip sub-second / small programs — the exact
-        # programs a CPU test fleet compiles; cache everything
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          float(min_compile_secs))
+    dirpath = compile_dir(root)
+    if external_dir() is None:
         try:
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-        except AttributeError:  # older jax: size threshold didn't exist
-            pass
-        _ENABLED_DIR = dirpath
-        return dirpath
-    except Exception as e:  # noqa: BLE001 - degrade to cold compiles
-        if not quiet:
-            warnings.warn(f"compile cache disabled: {type(e).__name__}: {e}")
-        _ENABLED_DIR = None
-        return None
+            sweep(dirpath)
+        except OSError as e:
+            warnings.warn(f"compile cache disabled: cannot use {dirpath}: "
+                          f"{type(e).__name__}: {e}")
+            _ENABLED_DIR = None
+            return None
+    jax.config.update("jax_compilation_cache_dir", dirpath)
+    # jax memoizes "is the cache used" at the FIRST compilation of the
+    # process; a long-lived process (tests, notebooks) that compiled
+    # anything before enable() has latched False — reset to pristine so
+    # the new directory takes effect
+    _reset_jax_cache_state()
+    # default thresholds skip sub-second / small programs — the exact
+    # programs a CPU test fleet compiles; cache everything
+    jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                      float(min_compile_secs))
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    _ENABLED_DIR = dirpath
+    return dirpath
 
 
 def disable() -> None:
@@ -273,12 +284,8 @@ def disable() -> None:
 
 
 def _reset_jax_cache_state() -> None:
-    try:
-        from jax.experimental.compilation_cache import (
-            compilation_cache as jcc)
-        jcc.reset_cache()
-    except Exception:  # noqa: BLE001 - older jax: no latch to reset
-        pass
+    from jax.experimental.compilation_cache import compilation_cache as jcc
+    jcc.reset_cache()
 
 
 def enabled_dir() -> str | None:

@@ -72,14 +72,6 @@ _DTYPE_BYTES = {
 _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
 
 
-def _mesh_context(mesh):
-    """`jax.set_mesh(mesh)` on new jax; on <=0.4 the Mesh IS the context."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
-
-
 def _shape_bytes(shape_str: str) -> int:
     """Bytes of one HLO shape like 'f32[16,128]' (tuples handled upstream)."""
     m = _SHAPE_RE.match(shape_str)
@@ -195,7 +187,7 @@ def build_train_lowering(arch: str, shape_name: str, mesh, *,
         out_shardings=(pshard, oshard, dshard, None),
         donate_argnums=(0, 1, 2),  # params/opt/dp buffers update in place
     )
-    with _mesh_context(mesh):
+    with jax.set_mesh(mesh):
         lowered = jitted.lower(params_abs, opt_abs, dp_abs, batch_abs,
                                key_abs)
     return lowered, model, cfg
@@ -228,7 +220,7 @@ def build_serve_lowering(arch: str, shape_name: str, mesh, *,
         bshard = batch_shardings(batch_abs, mesh)
         jitted = jax.jit(model.prefill_step,
                          in_shardings=(pshard, bshard), out_shardings=None)
-        with _mesh_context(mesh):
+        with jax.set_mesh(mesh):
             lowered = jitted.lower(params_abs, batch_abs)
         return lowered, model, cfg
 
@@ -240,7 +232,7 @@ def build_serve_lowering(arch: str, shape_name: str, mesh, *,
                      in_shardings=(pshard, cshard, bshard),
                      out_shardings=(None, cshard),
                      donate_argnums=(1,))  # KV/state cache updates in place
-    with _mesh_context(mesh):
+    with jax.set_mesh(mesh):
         lowered = jitted.lower(params_abs, cache_abs, batch_abs)
     return lowered, model, cfg
 

@@ -17,11 +17,16 @@ Two entry points share the model's `serve_step`:
 
   PYTHONPATH=src python -m repro.launch.serve --arch rwkv6-7b --reduced \\
       --batch 4 --prompt-len 16 --min-prompt-len 4 --gen 32 --slots 3
+
+`run(argv)` is the whole CLI as a function: it returns a `ServeRun` with
+the engine it drove and the tokens it generated.
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
+from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -145,6 +150,9 @@ def build_serve_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tiny", choices=ARCH_IDS + ["tiny"])
     ap.add_argument("--reduced", action="store_true")
+    ap.add_argument("--layers", type=int, default=None, metavar="N",
+                    help="keep only the first N layers of the config "
+                         "(depth cut; every width stays as published)")
     ap.add_argument("--batch", type=int, default=4,
                     help="number of requests in the synthetic set")
     ap.add_argument("--prompt-len", type=int, default=16,
@@ -218,18 +226,29 @@ def build_serve_parser() -> argparse.ArgumentParser:
     return ap
 
 
-def main():
-    args = build_serve_parser().parse_args()
+@dataclasses.dataclass
+class ServeRun:
+    """What one run of the serving CLI did."""
 
-    from repro.launch.train import record_cache_program, setup_caches
+    cfg: Any
+    engine: Any  # the DecodeEngine (None in --mode batch)
+    requests: list  # the prompts served, shared prefix included
+    tokens: np.ndarray  # (requests, gen) generated ids, -1 past the end
+
+
+def run(argv=None) -> ServeRun:
+    """Parse `argv` like the CLI and serve; returns what the run did."""
+    args = build_serve_parser().parse_args(argv)
+
+    from repro.launch.train import (config_from_args, record_cache_program,
+                                    setup_caches)
     setup_caches(args)
 
-    cfg = get_config(args.arch, reduced=args.reduced)
+    cfg = config_from_args(args)
     if args.tenants is not None:
         if args.mode != "engine":
             raise SystemExit("--tenants requires --mode engine")
-        import dataclasses as _dc
-        cfg = _dc.replace(cfg, lora_rank=args.lora_rank)
+        cfg = dataclasses.replace(cfg, lora_rank=args.lora_rank)
     model = build_model(cfg)
     params = init_params(model.spec, jax.random.PRNGKey(args.seed))
     record_cache_program(args, entry="serve", arch=cfg.name)
@@ -256,6 +275,7 @@ def main():
     scope.enter_context(KB.scoped(args.backend,
                                   autotune=args.autotune != "off"))
 
+    eng = None
     t0 = time.time()
     if args.mode == "engine":
         from repro.launch.engine import DecodeEngine
@@ -337,6 +357,11 @@ def main():
           f"generated {args.gen} tokens/seq in {wall:.2f}s "
           f"({total / wall:.1f} tok/s incl. prefill)")
     print(toks[:, :16])
+    return ServeRun(cfg=cfg, engine=eng, requests=reqs, tokens=toks)
+
+
+def main(argv=None) -> int:
+    run(argv)
     return 0
 
 

@@ -4,12 +4,17 @@ same code path jits under the production mesh on TPU).
 Example:
   PYTHONPATH=src python -m repro.launch.train --arch qwen3-4b --reduced \\
       --clipping per_layer --epsilon 8 --steps 50 --batch 16 --seq 64
+
+`run(argv)` is the whole CLI as a function: it returns a `TrainRun` with the
+compiled step program and the per-step readings, so a caller in the same
+process (chip_smoke.py, tests) can check what the command did.
 """
 from __future__ import annotations
 
 import argparse
-import json
+import dataclasses
 import time
+from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -29,14 +34,26 @@ def parse_mesh(arg: str | None):
     """'--mesh DxM' -> a (data, model) mesh over the first D*M devices."""
     if not arg:
         return None
+    from repro.launch.mesh import make_debug_mesh
     d, m = (int(x) for x in arg.lower().split("x"))
-    return jax.make_mesh((d, m), ("data", "model"))
+    return make_debug_mesh(d, m)
+
+
+def config_from_args(args):
+    """The model config the CLI flags select: `--layers N` cuts the depth
+    and changes nothing else, so every width stays the published one."""
+    cfg = get_config(args.arch, reduced=args.reduced,
+                     variant=getattr(args, "variant", None))
+    if args.layers is not None:
+        if args.layers < 1:
+            raise ValueError(f"--layers must be >= 1, got {args.layers}")
+        cfg = dataclasses.replace(cfg, num_layers=args.layers)
+    return cfg
 
 
 def build_everything(args):
-    cfg = get_config(args.arch, reduced=args.reduced, variant=args.variant)
+    cfg = config_from_args(args)
     if args.lora_rank:
-        import dataclasses
         cfg = dataclasses.replace(cfg, lora_rank=args.lora_rank)
     model = build_model(cfg)
     mesh = parse_mesh(args.mesh)
@@ -97,6 +114,9 @@ def build_arg_parser(**kwargs) -> argparse.ArgumentParser:
                     choices=ARCH_IDS + ["tiny"])
     ap.add_argument("--reduced", action="store_true")
     ap.add_argument("--variant", default=None)
+    ap.add_argument("--layers", type=int, default=None, metavar="N",
+                    help="keep only the first N layers of the config "
+                         "(depth cut; every width stays as published)")
     ap.add_argument("--clipping", default="per_layer")
     ap.add_argument("--execution", default="bk", choices=["bk", "twopass"],
                     help="flat/group clipping execution: bk runs ONE "
@@ -186,8 +206,9 @@ def jit_step(step_fn, model, mesh):
 def setup_caches(args) -> None:
     """Enable the persistent compile cache and install the autotune table
     per the shared --cache/--autotune/--cache-dir flags (train, service,
-    and serve all start here). Best-effort: cache trouble never kills a
-    worker — it degrades to a cold compile / the static cost model."""
+    and serve all start here). An unusable cache root is reported and
+    degrades to a cold compile; a missing or stale autotune table to the
+    static cost model."""
     from repro.kernels import autotune
     from repro.launch import compile_cache
     if getattr(args, "cache", "on") != "off":
@@ -213,8 +234,22 @@ def record_cache_program(args, *, entry: str, arch: str) -> None:
     }, root=getattr(args, "cache_dir", None))
 
 
-def main():
-    args = build_arg_parser().parse_args()
+@dataclasses.dataclass
+class TrainRun:
+    """What one run of the training CLI did."""
+
+    cfg: Any
+    mesh: Any
+    compiled: Any  # the AOT-compiled step (`.as_text()` is its HLO)
+    compile_s: float  # lower + compile (or cache load) of the step
+    step_s: list  # wall seconds per step, each ended by its metrics fetch
+    metrics: list  # per step: {loss, clip_fraction, mean_threshold, ...}
+    params: Any  # the final parameters
+
+
+def run(argv=None) -> TrainRun:
+    """Parse `argv` like the CLI and train; returns what the run did."""
+    args = build_arg_parser().parse_args(argv)
     setup_caches(args)
 
     (cfg, model, rows, sampler, init_fn, step_fn, plan,
@@ -243,27 +278,42 @@ def main():
     step = jit_step(step_fn, model, mesh)
     key = jax.random.PRNGKey(args.seed + 1)
 
-    print(f"# arch={cfg.name} params={model.num_params:,} "
+    print(f"# arch={cfg.name} layers={cfg.num_layers} "
+          f"params={model.num_params:,} "
           f"groups={model.layout.num_groups} mode={plan.config.mode} "
           f"backend={plan.config.backend} "
           f"mesh={dict(mesh.shape) if mesh is not None else None} "
           f"sigma={plan.sigma:.3f} sigma_new={plan.sigma_new:.3f} "
           f"sigma_b={plan.sigma_b:.3f}")
-    t_start = time.time()
-    ran = 0
+    compiled, compile_s = None, 0.0
+    step_s, metrics = [], []
     for i in range(start_step, args.steps):
         idx = sampler.next_indices()
         batch = make_lm_batch(rows, idx, args.batch)
         batch = {k: jnp.asarray(v) for k, v in batch.items()}
-        params, opt_state, dp_state, met = step(
-            params, opt_state, dp_state, batch, key)
-        ran += 1
+        state = (params, opt_state, dp_state, batch, key)
+        if compiled is None:
+            # compile ahead of the first step, so compile time and step
+            # time are reported apart
+            t0 = time.perf_counter()
+            compiled = step.lower(*state).compile()
+            compile_s = time.perf_counter() - t0
+            print(f"# step compiled in {compile_s:.2f}s", flush=True)
+        t0 = time.perf_counter()
+        # the step's outputs may come back sharded differently from the
+        # initial state: place every argument where the program expects it
+        state = jax.device_put(state, compiled.input_shardings[0])
+        params, opt_state, dp_state, met = compiled(*state)
+        met = {k: float(v) for k, v in jax.device_get(met)._asdict().items()}
+        step_s.append(time.perf_counter() - t0)
+        metrics.append(met)
         if i % args.log_every == 0 or i == args.steps - 1:
-            print(f"step {i:5d} loss {float(met.loss):.4f} "
-                  f"clip_frac {float(met.clip_fraction):.3f} "
-                  f"thr {float(met.mean_threshold):.4f} "
-                  f"gnorm {float(met.grad_norm):.4f}", flush=True)
-    wall = time.time() - t_start
+            print(f"step {i:5d} loss {met['loss']:.4f} "
+                  f"clip_frac {met['clip_fraction']:.3f} "
+                  f"thr {met['mean_threshold']:.4f} "
+                  f"gnorm {met['grad_norm']:.4f}", flush=True)
+    ran = len(step_s)
+    wall = sum(step_s)
     if plan.config.private and ran:
         eps = compute_epsilon(sigma=plan.sigma,
                               sampling_rate=plan.config.sampling_rate,
@@ -277,6 +327,13 @@ def main():
             {"params": params, "opt_state": opt_state, "dp_state": dp_state},
             meta={"sampler": sampler.state()})
         print(f"# checkpoint: {path}")
+    return TrainRun(cfg=cfg, mesh=mesh, compiled=compiled,
+                    compile_s=compile_s, step_s=step_s, metrics=metrics,
+                    params=params)
+
+
+def main(argv=None) -> int:
+    run(argv)
     return 0
 
 

@@ -46,7 +46,7 @@ from repro.core.dp_sgd import DPConfig, make_dp_train_step
 from repro.core.spec import abstract_params, init_params
 from repro.launch.hlo_analysis import model_axis_norm_collectives
 from repro.launch.inputs import concrete_train_batch
-from repro.launch.mesh import named_shard_map
+from repro.launch.mesh import make_debug_mesh, named_shard_map
 from repro.launch.sharding import group_shard_assignment
 from repro.models.transformer import build_model
 
@@ -321,7 +321,7 @@ def main() -> int:
         m = build_model(cfg)
         params = init_params(m.spec, jax.random.PRNGKey(0))
         batch = concrete_train_batch(cfg, B, T, jax.random.PRNGKey(1))
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_debug_mesh(2, 4)
         mesh4 = jax.sharding.Mesh(
             np.asarray(jax.devices()[:4]).reshape(2, 2), ("data", "model"))
         assign = group_shard_assignment(m.layout, 4)

@@ -128,6 +128,25 @@ def test_bk_lora_trainable_key_equals_twopass():
     _assert_trees_close(r_bk.grads, r_tp.grads)
 
 
+def test_probe_failure_raises_instead_of_falling_back(tiny):
+    """Only the documented layouts fall back to twopass. A loss that cannot
+    trace with channel leaves is a bug; a silent fallback would double the
+    backward passes with nothing to tell the two apart."""
+    cfg, m, params, batch = tiny
+
+    def broken_loss(p, b, th):
+        if any(isinstance(v, bk.BkChannel) for v in th.values()):
+            raise RuntimeError("recorder bug")
+        return m.loss_fn(p, b, th)
+
+    with pytest.raises(RuntimeError, match="recorder bug"):
+        bk.probe_recipes(broken_loss, params, batch, m.layout, B)
+    with pytest.raises(RuntimeError, match="recorder bug"):
+        dp_clipped_gradients(broken_loss, params, batch, m.layout,
+                             mode="ghost_flat", batch_size=B,
+                             flat_threshold=0.5, execution="bk")
+
+
 def test_bk_falls_back_on_shared_site_params():
     """Zamba2's shared attention block (sensitivity_mult > 1) cannot be
     captured — one threshold leaf, many runtime sites — so the probe must

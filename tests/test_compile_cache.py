@@ -16,6 +16,13 @@ import pytest
 from repro.launch import compile_cache as cc
 
 
+@pytest.fixture(autouse=True)
+def _no_external_dir(monkeypatch):
+    """These cases place the cache themselves: a JAX_COMPILATION_CACHE_DIR
+    inherited from the environment would otherwise take precedence."""
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+
+
 @pytest.fixture
 def cache_off():
     """Guarantee the process-global jax cache config is restored."""
@@ -203,8 +210,25 @@ def test_enable_populates_and_survives_corruption(tmp_path, cache_off):
 def test_enable_is_best_effort_on_unwritable_root(tmp_path, cache_off):
     blocker = tmp_path / "flat"
     blocker.write_text("not a directory")
-    assert cc.enable(str(blocker)) is None  # degraded, not raised
+    with pytest.warns(UserWarning, match="compile cache disabled"):
+        assert cc.enable(str(blocker)) is None  # reported, not raised
     assert cc.enabled_dir() is None
+
+
+def test_external_cache_dir_is_used_and_never_swept(tmp_path, monkeypatch,
+                                                    cache_off):
+    """JAX_COMPILATION_CACHE_DIR wins over the repo-local root, and the
+    directory is left exactly as its owner put it: no sweep, no deletes,
+    no manifest — even of an entry the sweep would call torn."""
+    ext = tmp_path / "external"
+    torn = _fake_entry(str(ext), "torn", blob=b"not a cache entry")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(ext))
+    assert cc.compile_dir(str(tmp_path / "root")) == str(ext)
+    assert cc.enable(str(tmp_path / "root")) == str(ext)
+    assert jax.config.jax_compilation_cache_dir == str(ext)
+    assert os.path.exists(torn)
+    assert sorted(os.listdir(ext)) == ["torn-cache"]
+    assert not (tmp_path / "root").exists()
 
 
 # ---------------------------------------------------------------------------

@@ -34,6 +34,7 @@ OTHER_CLI_FLAGS = {
     "--sweep", "--show", "--full",          # repro.kernels.autotune
     "--smoke",                              # benchmarks.* smoke modes
     "--shape", "--audit",                   # repro.launch.dryrun
+    "--chips",                              # chip_smoke.py
 }
 
 PARSERS = {

@@ -9,9 +9,11 @@ except ImportError:  # container lacks hypothesis; deterministic shim
     from _hypothesis_compat import given, settings, st
 
 from repro.kernels import ref
+from repro.kernels.bk import scale_contract
 from repro.kernels.clip_reduce import clip_reduce
 from repro.kernels.fused_clip import fused_norm_clip
 from repro.kernels.ghost_norm import ghost_norm, ghost_norm_blocked
+from repro.kernels.paged_attn import paged_attn
 
 SHAPES = [
     (2, 8, 16, 24),
@@ -30,7 +32,7 @@ def test_ghost_norm_kernel(shape, dtype):
     a = jax.random.normal(key, (b, t, din)).astype(dtype)
     g = (jax.random.normal(jax.random.fold_in(key, 1), (b, t, dout)) * 0.1
          ).astype(dtype)
-    got = ghost_norm(a, g, bt=128, dk=128)
+    got = ghost_norm(a, g, bt=128, dk=128, interpret=True)
     want = ref.ghost_norm_ref(a, g)
     rtol = 4e-3 if dtype == jnp.bfloat16 else 1e-4
     np.testing.assert_allclose(got, want, rtol=rtol)
@@ -45,7 +47,7 @@ def test_clip_reduce_kernel(shape, dtype):
     g = (jax.random.normal(jax.random.fold_in(key, 1), (b, t, dout)) * 0.1
          ).astype(dtype)
     f = jax.random.uniform(jax.random.fold_in(key, 2), (b,))
-    got = clip_reduce(a, g, f, bi=128, bj=128, bt=128)
+    got = clip_reduce(a, g, f, bi=128, bj=128, bt=128, interpret=True)
     want = ref.clip_reduce_ref(a, g, f)
     rtol = 4e-3 if dtype == jnp.bfloat16 else 1e-4
     np.testing.assert_allclose(got, want, rtol=rtol, atol=1e-4)
@@ -58,7 +60,7 @@ def test_ghost_norm_property(b, t, din, dout):
     key = jax.random.PRNGKey(b * 997 + t)
     a = jax.random.normal(key, (b, t, din))
     g = jax.random.normal(jax.random.fold_in(key, 1), (b, t, dout))
-    got = ghost_norm(a, g, bt=32, dk=32)
+    got = ghost_norm(a, g, bt=32, dk=32, interpret=True)
     want = ref.ghost_norm_ref(a, g)
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=1e-5)
     assert bool(jnp.all(got >= -1e-5))  # norms² are nonnegative
@@ -71,7 +73,7 @@ def test_kernel_block_shape_sweep():
     want = ref.ghost_norm_ref(a, g)
     for bt in (32, 64, 256):
         for dk in (32, 128):
-            got = ghost_norm(a, g, bt=bt, dk=dk)
+            got = ghost_norm(a, g, bt=bt, dk=dk, interpret=True)
             np.testing.assert_allclose(got, want, rtol=2e-4)
 
 
@@ -97,7 +99,8 @@ def test_ghost_norm_blocked_kernel(case):
     key = jax.random.PRNGKey(zlib.crc32(repr(case).encode()) & 0xFFFF)
     a = jax.random.normal(key, (b, t, din))
     g = jax.random.normal(jax.random.fold_in(key, 1), (b, t, dout)) * 0.1
-    got = ghost_norm_blocked(a, g, m, block_axis=axis, bt=32, dk=32)
+    got = ghost_norm_blocked(a, g, m, block_axis=axis, bt=32, dk=32,
+                             interpret=True)
     want = ref.ghost_norm_blocked_ref(a, g, m, block_axis=axis)
     np.testing.assert_allclose(got, want, rtol=1e-4)
     # per-block norms² must sum to the full-layer norm²
@@ -136,7 +139,37 @@ def test_fused_norm_clip_kernel(case, with_extra):
              if with_extra else None)
     # exercise the whole threshold encoding: clip, pass-through, direct scale
     c = jnp.array(([0.5, jnp.inf, -0.7, 0.01] * b)[:b])
-    got_n, got_dw = fused_norm_clip(a, g, c, extra, bt=32)
+    got_n, got_dw = fused_norm_clip(a, g, c, extra, bt=32, interpret=True)
     want_n, want_dw = ref.fused_norm_clip_ref(a, g, c, extra)
     np.testing.assert_allclose(got_n, want_n, rtol=1e-4)
     np.testing.assert_allclose(got_dw, want_dw, rtol=1e-4, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# Compiled by default: interpret mode only when a caller asks for it.
+# ---------------------------------------------------------------------------
+
+_A, _G = jnp.ones((2, 8, 16)), jnp.ones((2, 8, 16))
+_F = jnp.ones((2,))
+DEFAULT_MODE_CALLS = {
+    "ghost_norm": lambda: ghost_norm(_A, _G),
+    "ghost_norm_blocked": lambda: ghost_norm_blocked(_A, _G, 2),
+    "fused_norm_clip": lambda: fused_norm_clip(_A, _G, _F),
+    "clip_reduce": lambda: clip_reduce(_A, _G, _F),
+    "scale_contract": lambda: scale_contract(_A, _G, _F),
+    "paged_attn": lambda: paged_attn(
+        jnp.ones((2, 1, 1, 16)), jnp.ones((3, 8, 1, 16)),
+        jnp.ones((3, 8, 1, 16)), jnp.zeros((2, 1), jnp.int32),
+        jnp.zeros((2,), jnp.int32), scale=0.25),
+}
+
+
+@pytest.mark.parametrize("name", sorted(DEFAULT_MODE_CALLS))
+def test_kernel_without_interpret_flag_compiles(name):
+    """Called without `interpret=`, a kernel goes to the Mosaic compiler,
+    which the CPU backend refuses: a fallback to the interpreter would
+    hide the device from a run that believes it is on one."""
+    if jax.default_backend() == "tpu":
+        pytest.skip("the compiled path runs on a TPU")
+    with pytest.raises(ValueError, match="interpret mode"):
+        DEFAULT_MODE_CALLS[name]()

@@ -47,6 +47,42 @@ def test_hlo_analyzer_tuple_shapes_with_index_comments():
     assert "known_trip_count" in rest
 
 
+# The form the TPU compiler gives a BK step's layer loops: double-buffered
+# `wide.*` whiles without a known_trip_count annotation (the bound sits in
+# the condition), and matmuls emitted as `convolution`.
+_TPU_LOOPS_HLO = """HloModule step
+
+%wide.cond.1 (arg: (s32[], f32[8,8])) -> pred[] {
+  %constant.7 = s32[]{:T(128)} constant(4)
+  %arg = (s32[]{:T(128)}, f32[8,8]{1,0}) parameter(0)
+  %gte.1 = s32[]{:T(128)} get-tuple-element(%arg), index=0
+  ROOT %lt.1 = pred[]{:T(512)} compare(%gte.1, %constant.7), direction=LT
+}
+
+%wide.body.1 (arg: (s32[], f32[8,8])) -> (s32[], f32[8,8]) {
+  %arg = (s32[]{:T(128)}, f32[8,8]{1,0}) parameter(0)
+  %gte.2 = f32[8,8]{1,0} get-tuple-element(%arg), index=1
+  %convolution.1 = f32[8,8]{1,0} convolution(%gte.2, %gte.2), dim_labels=bf_io->bf, metadata={op_name="jit(step_fn)/%s/while/body/dot_general"}
+  %gte.3 = s32[]{:T(128)} get-tuple-element(%arg), index=0
+  ROOT %tuple.1 = (s32[]{:T(128)}, f32[8,8]{1,0}) tuple(%gte.3, %convolution.1)
+}
+
+ENTRY %main (p0: (s32[], f32[8,8])) -> (s32[], f32[8,8]) {
+  %p0 = (s32[]{:T(128)}, f32[8,8]{1,0}) parameter(0)
+  ROOT %while.1 = (s32[]{:T(128)}, f32[8,8]{1,0}) while(%p0), condition=%wide.cond.1, body=%wide.body.1
+}
+"""
+
+
+@pytest.mark.parametrize("scope,passes", [("transpose(jvp())", 1),
+                                          ("jvp()", 0)])
+def test_backward_passes_reads_tpu_layer_loops(scope, passes):
+    from repro.analysis.hlo import backward_passes
+    text = _TPU_LOOPS_HLO.replace("%s", scope)
+    assert backward_passes(text, 4) == passes
+    assert backward_passes(text, 3) == 0  # the bound must match the depth
+
+
 def test_input_specs_cover_archs():
     for arch in ("qwen3-4b", "whisper-medium", "qwen2-vl-72b"):
         cfg = get_config(arch)

@@ -26,7 +26,8 @@ cfg = PipelineConfig(n_stages=2, layers_per_stage=3, d_model=16, d_in=8,
 spec = pipeline_spec(cfg)
 layout = GroupLayout(spec)
 params = init_params(spec, jax.random.PRNGKey(0))
-mesh = jax.make_mesh((2,), ("pod",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2,), ("pod",))
 loss_pipe = make_pipeline_loss(cfg, mesh)
 
 B = 8
@@ -46,6 +47,7 @@ for a, b in zip(jax.tree_util.tree_leaves(gp), jax.tree_util.tree_leaves(gr)):
                                atol=1e-5)
 
 # per-DEVICE clipping: groups = stages (+ embed, head); two-pass driver
+# (the stage loop indexes its thresholds as arrays, which BK cannot trace)
 names = [g.name for g in layout.groups]
 stage_g = layout.group("stage")
 assign = np.zeros(layout.num_groups, np.int32)
@@ -61,10 +63,12 @@ cg = jnp.full((n_super,), 0.05)
 res_p = dp_clipped_gradients(
     lambda p, b, t: loss_pipe(p, b, t), params, batch, layout,
     mode="per_group", batch_size=B, group_assignment=jnp.asarray(assign),
+    execution="twopass",
     group_thresholds=cg)
 res_r = dp_clipped_gradients(
     lambda p, b, t: reference_loss(cfg, p, b, t), params, batch, layout,
     mode="per_group", batch_size=B, group_assignment=jnp.asarray(assign),
+    execution="twopass",
     group_thresholds=cg)
 for a, b in zip(jax.tree_util.tree_leaves(res_p.grads),
                 jax.tree_util.tree_leaves(res_r.grads)):
@@ -80,6 +84,7 @@ np.testing.assert_allclose(np.asarray(res_p.norms_sq),
 hlo = jax.jit(lambda p, t: dp_clipped_gradients(
     lambda pp, bb, tt: loss_pipe(pp, bb, tt), p, batch, layout,
     mode="per_group", batch_size=B, group_assignment=jnp.asarray(assign),
+    execution="twopass",
     group_thresholds=t).norms_sq).lower(params, cg).compile().as_text()
 n_perm = hlo.count(" collective-permute(")
 print(json.dumps({"ok": True, "n_ppermute": n_perm}))
