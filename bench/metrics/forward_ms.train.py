@@ -1,0 +1,12 @@
+"""Device milliseconds per training step in the forward phase: the ops
+under the step's clip scope (core.dp_sgd.PHASE_CLIP) that are not
+transposed, by the compiled step's own map (repro.analysis.hlo.op_phases).
+
+Their summed device time inside the step program's runs in the traced
+window, over the `bench.step` spans; nothing where the map leaves more than
+2 % of the step's op time unattributed (bench/phases.py)."""
+from bench import phases
+
+
+def read(run):
+    return phases.phase_ms(run, "forward")

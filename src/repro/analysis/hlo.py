@@ -22,6 +22,8 @@ import dataclasses
 import re
 from functools import lru_cache
 
+from repro.core.dp_sgd import PHASE_CLIP, PHASE_NOISE, PHASE_UPDATE
+
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "bf16": 2, "f16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
     "f8e4m3": 1, "f8e5m2fnuz": 1, "f8e4m3fnuz": 1,
@@ -140,6 +142,22 @@ _LHS_CONTRACT = re.compile(r"lhs_contracting_dims=\{([\d,]*)\}")
 _DOT_BATCH = re.compile(r"lhs_batch_dims=\{([\d,]*)\}")
 
 
+def _operand_names(ins: Instr) -> list[str]:
+    """The operands of `ins`: the leading %refs before the closing paren of
+    the operand list; attribute refs come after "), " — take refs up to the
+    first ")" at depth 0."""
+    depth, end = 1, len(ins.rest)
+    for idx, ch in enumerate(ins.rest):
+        if ch == "(":
+            depth += 1
+        elif ch == ")":
+            depth -= 1
+            if depth == 0:
+                end = idx
+                break
+    return _OPERANDS.findall(ins.rest[:end])
+
+
 class HloAnalyzer:
     def __init__(self, text: str):
         self.comps = parse_module(text)
@@ -172,20 +190,7 @@ class HloAnalyzer:
 
     def _operand_shapes(self, ins: Instr, shapes: dict[str, str]
                         ) -> list[str]:
-        # operands are the leading %refs before the closing paren of the
-        # operand list; attribute refs come after "), " — take refs up to
-        # the first ")" at depth 0
-        depth, end = 1, len(ins.rest)
-        for idx, ch in enumerate(ins.rest):
-            if ch == "(":
-                depth += 1
-            elif ch == ")":
-                depth -= 1
-                if depth == 0:
-                    end = idx
-                    break
-        ops = _OPERANDS.findall(ins.rest[:end])
-        return [shapes.get(o, "") for o in ops]
+        return [shapes.get(o, "") for o in _operand_names(ins)]
 
     def _instr(self, ins: Instr, shapes: dict[str, str], t: Totals) -> None:
         op = ins.op
@@ -714,3 +719,111 @@ def dynamic_shape_instrs(text: str) -> list[tuple[str, str]]:
         if parsed and "<=" in parsed[1]:
             out.append((parsed[0], parsed[1]))
     return out
+
+
+# ---------------------------------------------------------------------------
+# Step phases: which phase of the DP step each instruction belongs to.
+#
+# make_dp_train_step wraps each phase of the step in one named scope
+# (core.dp_sgd.PHASE_*), so the op_name of every instruction the step's JAX
+# code emits names its phase. A profiler trace names each device op by its
+# instruction name, so this map splits the step's device time by phase.
+# ---------------------------------------------------------------------------
+
+FORWARD, BACKWARD, NOISE_UPDATE = "forward", "backward", "noise_update"
+# their device events span the events of the computations they call
+CONTAINER_OPS = frozenset({"while", "conditional", "call"})
+_PHASE_SCOPE = re.compile(
+    rf"\b({PHASE_CLIP}|{PHASE_NOISE}|{PHASE_UPDATE})\b")
+# JAX names a transposed op `transpose(jvp(...))/...`, and the backward of a
+# custom_vjp `transpose(<scope>)/jvp(<fn>)/...`
+_TRANSPOSE = re.compile(r"transpose\(")
+
+
+def _called(ins: Instr) -> list[str]:
+    """The computations an instruction calls."""
+    names = [m.group(1) for m in _CALLED.finditer(ins.rest)]
+    bm = _BRANCHES.search(ins.rest)
+    if bm:
+        names += [b.strip().lstrip("%") for b in bm.group(1).split(",")]
+    return names + _TRUEFALSE.findall(ins.rest)
+
+
+def _named_phase(ins: Instr) -> str | None:
+    """The phase the instruction's own op_name names, if any."""
+    nm = _OPNAME.search(ins.rest)
+    m = _PHASE_SCOPE.search(nm.group(1)) if nm else None
+    if m is None:
+        return None
+    if m.group(1) != PHASE_CLIP:
+        # XLA fuses the noise's `g + z` into the optimizer's elementwise
+        # fusion, and a fusion carries its root's op_name: one phase
+        return NOISE_UPDATE
+    return BACKWARD if _TRANSPOSE.search(nm.group(1)) else FORWARD
+
+
+def _phases(comps: dict[str, list[Instr]]) -> dict[str, str | None]:
+    """{instruction: phase} for every instruction, containers included.
+
+    An instruction whose op_name names no phase (XLA's own copies, a
+    reducer's body, loop-invariant code JAX hoisted out of a scoped loop)
+    takes the phase of the instruction that calls its computation; failing
+    that, of its first user that has one, else of its first operand that
+    has one, until nothing changes. Only an instruction with no phased
+    caller, user or operand, however far removed, keeps None."""
+    phase: dict[str, str | None] = {}
+    caller: dict[str, str] = {}
+    for instrs in comps.values():
+        for ins in instrs:
+            phase[ins.name] = _named_phase(ins)
+            for c in _called(ins):
+                caller.setdefault(c, ins.name)
+    flow = {}  # comp -> [(instr, its users then its operands)]
+    for comp, instrs in comps.items():
+        operands = {ins.name: [o for o in _operand_names(ins) if o in phase]
+                    for ins in instrs}
+        users: dict[str, list[str]] = {ins.name: [] for ins in instrs}
+        for ins in instrs:
+            for o in operands[ins.name]:
+                if o in users:
+                    users[o].append(ins.name)
+        flow[comp] = [(ins.name, users[ins.name] + operands[ins.name])
+                      for ins in instrs]
+    changed = True
+    while changed:
+        changed = False
+        for comp, links in flow.items():
+            outer = phase.get(caller.get(comp, ""))
+            for name, near in links:
+                if phase[name] is not None:
+                    continue
+                got = outer or next(
+                    (phase[n] for n in near if phase[n] is not None), None)
+                if got is not None:
+                    phase[name] = got
+                    changed = True
+    return phase
+
+
+def op_phases(text: str) -> dict[str, str | None]:
+    """{instruction name: phase} over every computation of a compiled
+    DP step's module, containers (`CONTAINER_OPS`) left out.
+
+    forward       under the clip scope and not transposed
+    backward      under the clip scope and transposed: the norms, the
+                  clipped sums and forward ops rematerialised in the
+                  backward
+    noise_update  under the noise or the update scope
+    None          outside every phase scope (see `_phases`)
+    """
+    comps = parse_module(text)
+    phase = _phases(comps)
+    return {ins.name: phase[ins.name] for instrs in comps.values()
+            for ins in instrs if ins.op not in CONTAINER_OPS}
+
+
+def container_ops(text: str) -> set[str]:
+    """Names of the module's `while`, `conditional` and `call`
+    instructions, whose device events span their bodies' events."""
+    return {ins.name for instrs in parse_module(text).values()
+            for ins in instrs if ins.op in CONTAINER_OPS}

@@ -134,6 +134,15 @@ class StepMetrics(NamedTuple):
     grad_norm: jax.Array  # norm of the (noised, averaged) update direction
 
 
+# Named scopes of the step's phases: every op of a step lies under exactly
+# one, so a profiler trace or the compiled HLO splits the step by phase
+# (repro.analysis.hlo.op_phases). None contains "norm" (that marks norm
+# collectives) or starts with the auditor's "dp_noise_add" prefix.
+PHASE_CLIP = "dp_phase_clip"  # forward, backward, norms, clipped sums
+PHASE_NOISE = "dp_phase_noise"  # clip counts, noise stds, the noise draw
+PHASE_UPDATE = "dp_phase_update"  # keys, thresholds, optimizer, quantiles
+
+
 @dataclasses.dataclass(frozen=True)
 class DPPlan:
     """Everything precomputed at build time (python floats, accounting)."""
@@ -461,30 +470,36 @@ def make_dp_train_step(
             return _step(params, opt_state, dp_state, batch, key)
 
     def _step(params, opt_state, dp_state, batch, key):
-        k_noise, k_q = jax.random.split(jax.random.fold_in(key, dp_state.step))
-        thresholds = _effective_thresholds(cfg, plan, dp_state)
+        with jax.named_scope(PHASE_UPDATE):
+            k_noise, k_q = jax.random.split(
+                jax.random.fold_in(key, dp_state.step))
+            thresholds = _effective_thresholds(cfg, plan, dp_state)
 
-        res = _clip(params, batch, thresholds)
-        if mode == "non_private":
-            noised = res.grads
-            counts = jnp.zeros_like(thresholds)
-        else:
-            if mode == "per_layer":
-                counts = clip_counts(res.norms_sq, thresholds)
-            elif mode in ("ghost_flat", "naive_flat"):
-                counts = clip_counts(jnp.sum(res.norms_sq, axis=0)[None],
-                                     thresholds)
-            else:  # per_group
-                super_norms = jax.ops.segment_sum(
-                    res.norms_sq, assign, num_segments=plan.num_noise_groups)
-                counts = clip_counts(super_norms, thresholds)
-            stds, _ = _layout_stds(plan, layout, thresholds)
-            noised = add_noise_to_grads(spec, layout, res.grads, stds,
-                                        k_noise, cfg.noise_dtype)
+        with jax.named_scope(PHASE_CLIP):
+            res = _clip(params, batch, thresholds)
+        with jax.named_scope(PHASE_NOISE):
+            if mode == "non_private":
+                noised = res.grads
+                counts = jnp.zeros_like(thresholds)
+            else:
+                if mode == "per_layer":
+                    counts = clip_counts(res.norms_sq, thresholds)
+                elif mode in ("ghost_flat", "naive_flat"):
+                    counts = clip_counts(jnp.sum(res.norms_sq, axis=0)[None],
+                                         thresholds)
+                else:  # per_group
+                    super_norms = jax.ops.segment_sum(
+                        res.norms_sq, assign,
+                        num_segments=plan.num_noise_groups)
+                    counts = clip_counts(super_norms, thresholds)
+                stds, _ = _layout_stds(plan, layout, thresholds)
+                noised = add_noise_to_grads(spec, layout, res.grads, stds,
+                                            k_noise, cfg.noise_dtype)
 
-        return _apply_update(cfg, plan, optimizer, trainable_key, batch_size,
-                             params, opt_state, dp_state, noised, counts,
-                             thresholds, res.loss, k_q)
+        with jax.named_scope(PHASE_UPDATE):
+            return _apply_update(cfg, plan, optimizer, trainable_key,
+                                 batch_size, params, opt_state, dp_state,
+                                 noised, counts, thresholds, res.loss, k_q)
 
     return init_fn, step_fn, plan
 
@@ -598,23 +613,29 @@ def _make_sharded_step(loss_fn, spec, layout, optimizer, cfg: DPConfig, *,
 
     def _body(params, opt_state, dp_state, batch, key):
         with ghost_backend.scoped(cfg.backend, autotune=cfg.autotune):
-            k_noise, k_q = jax.random.split(
-                jax.random.fold_in(key, dp_state.step))
-            thresholds = _effective_thresholds(cfg, plan, dp_state)
+            with jax.named_scope(PHASE_UPDATE):
+                k_noise, k_q = jax.random.split(
+                    jax.random.fold_in(key, dp_state.step))
+                thresholds = _effective_thresholds(cfg, plan, dp_state)
 
-            res = _clip(params, batch, thresholds)
-            if mode == "non_private":
-                noised = res.grads
-                counts = jnp.zeros_like(thresholds)
-            else:
-                counts = res.counts  # globally reduced by the clip driver
-                stds, _ = _layout_stds(plan, layout, thresholds)
-                noised = add_noise_to_grads(spec, layout, res.grads, stds,
-                                            k_noise, cfg.noise_dtype)
+            with jax.named_scope(PHASE_CLIP):
+                res = _clip(params, batch, thresholds)
+            with jax.named_scope(PHASE_NOISE):
+                if mode == "non_private":
+                    noised = res.grads
+                    counts = jnp.zeros_like(thresholds)
+                else:
+                    counts = res.counts  # globally reduced by the clip driver
+                    stds, _ = _layout_stds(plan, layout, thresholds)
+                    noised = add_noise_to_grads(spec, layout, res.grads,
+                                                stds, k_noise,
+                                                cfg.noise_dtype)
 
-            return _apply_update(cfg, plan, optimizer, trainable_key,
-                                 batch_size, params, opt_state, dp_state,
-                                 noised, counts, thresholds, res.loss, k_q)
+            with jax.named_scope(PHASE_UPDATE):
+                return _apply_update(cfg, plan, optimizer, trainable_key,
+                                     batch_size, params, opt_state, dp_state,
+                                     noised, counts, thresholds, res.loss,
+                                     k_q)
 
     step_fn = named_shard_map(
         _body, mesh,

@@ -88,3 +88,61 @@ def test_kernel_compiles_for_v5e_at_qwen3_4b_widths(name, one_chip):
                     if 'custom_call_target="tpu_custom_call"' in ln]
     assert kernel_lines, f"{name}: no Mosaic kernel in the compiled program"
     assert any(f"{name}/pallas_call" in ln for ln in kernel_lines)
+
+
+def test_tiny_dp_step_phases_for_v5e(one_chip):
+    """The phase map on the TPU compiler's instruction names: every op of
+    a compiled private step gets one phase, every `ghost_norm` and
+    `clip_reduce` kernel lands in the backward, every noise draw in
+    noise_update, and each kernel's instruction name still holds the
+    kernel's name, which `ghost_norm_roofline.train` matches."""
+    import dataclasses
+    import re
+
+    from repro import optim
+    from repro.analysis import hlo
+    from repro.configs import get_config
+    from repro.core.dp_sgd import DPConfig, make_dp_train_step
+    from repro.core.spec import abstract_params
+    from repro.kernels import backend as KB
+    from repro.models.transformer import build_model
+
+    b, t = 4, 128
+    m = build_model(dataclasses.replace(get_config("tiny"), num_layers=2))
+    dpc = DPConfig(mode="per_layer", sigma=1.0, sampling_rate=0.1, steps=10,
+                   backend="pallas", autotune=False)
+    init_fn, step_fn, _ = make_dp_train_step(
+        m.loss_fn, m.spec, m.layout, optim.adam(1e-3), dpc, batch_size=b)
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(on_chip, abstract_params(m.spec))
+    opt_abs, dp_abs = jax.tree_util.tree_map(
+        on_chip, jax.eval_shape(init_fn, params))
+    batch = {k: on_chip(jax.ShapeDtypeStruct((b, t), jnp.int32))
+             for k in ("tokens", "targets")}
+    key = on_chip(jax.ShapeDtypeStruct((2,), jnp.uint32))
+    with KB.scoped("pallas", interpret=False, prefer_fused=False):
+        text = jax.jit(step_fn).lower(params, opt_abs, dp_abs, batch,
+                                      key).compile().as_text()
+    phases = hlo.op_phases(text)
+    kernels = {}
+    for instrs in hlo.parse_module(text).values():
+        for ins in instrs:
+            if ins.op in hlo.CONTAINER_OPS:
+                assert ins.name not in phases
+                continue
+            assert phases[ins.name] is not None, ins.name
+            if "dp_noise_add:" in ins.rest:
+                assert phases[ins.name] == hlo.NOISE_UPDATE, ins.name
+            k = re.search(r'op_name="[^"]*\b(ghost_norm|clip_reduce)\)?'
+                          r'/pallas_call"', ins.rest)
+            if k and 'custom_call_target="tpu_custom_call"' in ins.rest:
+                kernels.setdefault(k.group(1), []).append(ins.name)
+                assert phases[ins.name] == hlo.BACKWARD, ins.name
+    assert set(kernels) == {"ghost_norm", "clip_reduce"}
+    for kernel, names in kernels.items():
+        assert all(kernel in n for n in names), names
+    assert {hlo.FORWARD, hlo.BACKWARD, hlo.NOISE_UPDATE} <= set(
+        phases.values())
