@@ -266,8 +266,11 @@ def clipped_sum_linear(a: jax.Array, g: jax.Array, factors: jax.Array
 
     f32 accumulation throughout (like every clipped sum here): quantizing
     the clip factor to bf16 would let clipped contributions exceed the
-    sensitivity bound, and the Pallas clip_reduce kernel computes in f32 —
-    the reference must match it.
+    sensitivity bound. The Pallas clip_reduce kernel orders it otherwise
+    but keeps the factor in f32: bf16 operands go into the MXU as they are
+    (their products are exact in f32), each example's partial sum
+    accumulates in f32, and the f32 factor scales that sum. The two differ
+    only by the order of f32 rounding.
     """
     a3, g3 = _as3d(a).astype(ACC_DTYPE), _as3d(g).astype(ACC_DTYPE)
     gs = g3 * factors[:, None, None].astype(ACC_DTYPE)

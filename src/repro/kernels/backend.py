@@ -101,10 +101,16 @@ class EngineConfig:
       elements for the outer-product norms path, and the gram-matrix
       chunk size (elements along B·T). `None` inherits the
       `repro.core.ghost` module defaults.
-    * `bt`, `dk`, `bi`, `bj`: pallas tile sizes (rows of the sequence,
-      feature-chunk, din and dout tiles respectively; units = array
-      elements). Defaults suit ~16 MB VMEM cores; the autotune sweep
-      measures alternatives.
+    * `bt`, `dk`: pallas tile sizes of `ghost_norm` and the fused kernel
+      (rows of the sequence and feature chunk; units = array elements).
+      Defaults suit ~16 MB VMEM cores; the autotune sweep measures
+      alternatives.
+    * `bi`, `bj`: the din and dout tiles of `clip_reduce` and
+      `scale_contract`. `None` (the default) means derived:
+      `clip_reduce.tiles` sizes `clip_reduce`'s from the shape and dtype,
+      and `scale_contract` keeps its own 256. An explicit value overrides
+      both. `clip_reduce`'s row tile is always derived, since it has to
+      divide the (padded) sequence.
     * `interpret` (default `None` = interpret off-TPU, compiled on
       TPU): force pallas interpret mode either way.
     * `vmem_limit_bytes` (default 12 MiB): kernel-selection guard —
@@ -131,8 +137,8 @@ class EngineConfig:
     # pallas tile sizes
     bt: int = 256   # sequence tile (ghost_norm / fused)
     dk: int = 512   # feature-chunk tile (ghost_norm)
-    bi: int = 256   # clip_reduce din tile
-    bj: int = 256   # clip_reduce dout tile
+    bi: int | None = None   # clip_reduce / scale_contract din tile
+    bj: int | None = None   # clip_reduce / scale_contract dout tile
     # None -> interpret off TPU, compiled on TPU; bools force it
     interpret: bool | None = None
     # VMEM-footprint guard for kernel selection (bytes)
@@ -303,10 +309,14 @@ class PallasBackend(Backend):
                                   bt=self.config.bt, dk=self.config.dk,
                                   interpret=self._interpret())
 
+    def _feature_tiles(self) -> dict:
+        """The explicit din/dout tiles; absent ones are each kernel's own."""
+        return {k: v for k, v in (("bi", self.config.bi),
+                                  ("bj", self.config.bj)) if v is not None}
+
     def clipped_sum_linear(self, a, g, factors):
         a3, g3 = ghost._as3d(a), ghost._as3d(g)
-        return clip_reduce(a3, g3, factors, bi=self.config.bi,
-                           bj=self.config.bj, bt=self.config.bt,
+        return clip_reduce(a3, g3, factors, **self._feature_tiles(),
                            interpret=self._interpret())
 
     def clipped_sum_linear_blocked(self, a, g, factors, *, block_axis="out"):
@@ -316,8 +326,7 @@ class PallasBackend(Backend):
         a3, g3 = ghost.fold_block_factors(ghost._as3d(a), ghost._as3d(g),
                                           factors, block_axis)
         ones = jnp.ones((a3.shape[0],), jnp.float32)
-        return clip_reduce(a3, g3, ones, bi=self.config.bi,
-                           bj=self.config.bj, bt=self.config.bt,
+        return clip_reduce(a3, g3, ones, **self._feature_tiles(),
                            interpret=self._interpret())
 
     def linear_clip(self, a, g, c, extra_norms_sq=None):
@@ -332,8 +341,8 @@ class PallasBackend(Backend):
         return n, clip_factor(c, n), dw
 
     def scale_contract(self, a, g, factors):
-        return scale_contract_kernel(a, g, factors, bi=self.config.bi,
-                                     bj=self.config.bj, bt=self.config.bt,
+        return scale_contract_kernel(a, g, factors, **self._feature_tiles(),
+                                     bt=self.config.bt,
                                      interpret=self._interpret())
 
     def paged_impl(self, *, t=None, din=None, dout=None) -> str:

@@ -10,12 +10,11 @@ stack axis S (one slice per scanned layer):
 
     out[s] = Σ_i f[s, i] · A[s, i]ᵀ G[s, i]        (din × dout, f32)
 
-Layout mirrors `clip_reduce`: rows r = flattened (B·T) per stack slice,
-grid = (S, din/bi, dout/bj, R/bt) with r innermost and sequential; the
-per-row factor is fused into the RHS load so the scaled G never exists in
-HBM. VMEM per step: (bt×bi) + (bt×bj) + (bt×1) inputs + (bi×bj) f32
-accumulator ≈ 0.8 MiB at the 256-tile defaults — same budget as
-clip_reduce, once per stack slice.
+Layout: rows r = flattened (B·T) per stack slice, grid = (S, din/bi,
+dout/bj, R/bt) with r innermost and sequential; the per-row factor is
+fused into the RHS load so the scaled G never exists in HBM. VMEM per
+step: (bt×bi) + (bt×bj) + (bt×1) inputs + (bi×bj) f32 accumulator ≈ 0.8
+MiB at the 256-tile defaults, once per stack slice.
 """
 from __future__ import annotations
 

@@ -1,15 +1,38 @@
-"""Pallas TPU kernel: fused clip-scale-accumulate  Σ_i c_i A_iᵀ G_i.
+"""Pallas TPU kernel: fused clip-scale-accumulate  Σ_b c_b A_bᵀ G_b.
 
 The second half of the paper's fused per-layer clipping op: once clip
-factors c_i are known, the clipped summed weight gradient is one scaled
-contraction. The kernel fuses the per-row scaling into the matmul's RHS
-load so the scaled G is never written to HBM:
+factors c_b are known, the clipped summed weight gradient is one scaled
+contraction, and the scaled G is never written to HBM:
 
-  rows r = flattened (B·T);    grid = (din/bi, dout/bj, R/bt)  (r innermost)
-  acc(bi, bj) f32 scratch; acc += A[r-block]ᵀ (G[r-block] ⊙ c[r-block])
+  rows r = flattened (B·T'), T' = T rounded up to a multiple of bt (zero
+  rows, which add nothing, only where T is not one already); so a row
+  block belongs to one example, b = r // (T'/bt).
+  grid = (cdiv(din, bi), cdiv(dout, bj), B·T'/bt)   (r innermost)
+  out(bi, bj) f32, resident over r:  out += c_b · (A[r-block]ᵀ G[r-block])
 
-VMEM: (bt x bi) + (bt x bj) + (bt x 1) + acc (bi x bj) f32
-  = 256·256·4·3 + 256·4 ≈ 0.8 MiB.  MXU dims (bi, bj, bt) all 128-aligned.
+The operands enter the MXU in their own dtype (bf16 in training: its
+products are exact in f32), the MXU accumulates in f32, and c_b, an
+unquantized f32 read from SMEM, scales the f32 partial sum of its example.
+Mixed operand dtypes are promoted in VMEM; f32 operands stay f32.
+
+Ragged edges cost no copies: the last block along din or dout reads past
+the array's edge, and what it reads there lands only in output rows or
+columns past the edge, which the write-back masks. The contracted row
+axis is exact.
+
+Tiles come from the shape (`tiles`): bt = T split into equal blocks of at
+most 512 rows; bi, bj = din, dout split into the fewest equal 128-aligned
+blocks of at most 2560 (the whole axis where it fits). Larger tiles won at
+every shape of the qwen3-4b cell on a TPU v5e (PERF.md): each grid
+step's bi·bj/(bi + bj) FLOP per operand byte is past the chip's ridge, and
+the per-step cost of the accumulate and of transposing the A block
+shrinks. VMEM, with the output block as the accumulator:
+  2·bt·(bi + bj)·itemsize     double-buffered operand blocks
+  + 2·bi·bj·4                 double-buffered f32 output block
+  + bi·bj·4 + bt·bi·itemsize  the dot's result and the transposed A block
+= 10 + 50 + 27.5 MiB at (512, 2560, 2560) in bf16 (and 100 MiB at most in
+f32), past the default scoped limit, so the kernel asks Mosaic for it and a
+quarter more (`vmem_limit_bytes`), within a v5e core's 128 MiB.
 """
 from __future__ import annotations
 
@@ -20,64 +43,76 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-DEFAULT_BI = 256
-DEFAULT_BJ = 256
-DEFAULT_BT = 256
+BT_MAX = 512
+BF_MAX = 2560
 
 
-def _kernel(a_ref, g_ref, c_ref, out_ref, acc, *, nr):
+def _split(n: int, cap: int, align: int) -> int:
+    """The tile that cuts n into the fewest equal blocks of at most `cap`,
+    rounded up to `align`; the whole axis where it fits."""
+    if n <= cap:
+        return n
+    k = pl.cdiv(n, cap)
+    return pl.cdiv(pl.cdiv(n, k), align) * align
+
+
+def tiles(t: int, din: int, dout: int, dtype) -> tuple[int, int, int]:
+    """The derived (bi, bj, bt) for operands of `dtype` at (T, din, dout).
+
+    bt is a multiple of the dtype's sublane tile (8 rows of f32, 16 of
+    bf16), so the zero-row pad of T up to a multiple of bt is at most a
+    few rows per example, and none at T = 512."""
+    sub = 32 // jnp.dtype(dtype).itemsize
+    bt = pl.cdiv(pl.cdiv(t, pl.cdiv(t, BT_MAX)), sub) * sub
+    return _split(din, BF_MAX, 128), _split(dout, BF_MAX, 128), bt
+
+
+def _kernel(c_ref, a_ref, g_ref, out_ref, *, per_example, dtype):
     r = pl.program_id(2)
 
     @pl.when(r == 0)
     def _init():
-        acc[...] = jnp.zeros_like(acc)
+        out_ref[...] = jnp.zeros_like(out_ref)
 
-    a_blk = a_ref[...].astype(jnp.float32)  # (bt, bi)
-    g_blk = g_ref[...].astype(jnp.float32)  # (bt, bj)
-    c_blk = c_ref[...].astype(jnp.float32)  # (bt, 1)
-    acc[...] += jax.lax.dot_general(
-        a_blk, g_blk * c_blk, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-
-    @pl.when(r == nr - 1)
-    def _emit():
-        out_ref[...] = acc[...]
+    part = jax.lax.dot_general(
+        a_ref[...].astype(dtype), g_ref[...].astype(dtype),
+        (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    out_ref[...] += c_ref[r // per_example] * part
 
 
 def clip_reduce(a: jax.Array, g: jax.Array, factors: jax.Array, *,
-                bi: int = DEFAULT_BI, bj: int = DEFAULT_BJ,
-                bt: int = DEFAULT_BT, interpret: bool = False) -> jax.Array:
-    """(din, dout) = Σ_i c_i A_iᵀ G_i.  a: (B,T,din); g: (B,T,dout);
-    factors: (B,)."""
+                bi: int | None = None, bj: int | None = None,
+                bt: int | None = None, interpret: bool = False) -> jax.Array:
+    """(din, dout) f32 = Σ_b c_b A_bᵀ G_b.  a: (B,T,din); g: (B,T,dout);
+    factors: (B,). A tile left `None` comes from `tiles`."""
     b, t, din = a.shape
     dout = g.shape[-1]
-    rows = b * t
-    a2 = a.reshape(rows, din)
-    g2 = g.reshape(rows, dout)
-    c2 = jnp.repeat(factors.astype(jnp.float32), t)[:, None]  # (rows, 1)
-    bi = min(bi, din)
-    bj = min(bj, dout)
-    bt = min(bt, rows)
-    dip = -(-din // bi) * bi
-    djp = -(-dout // bj) * bj
-    rp = -(-rows // bt) * bt
-    a2 = jnp.pad(a2, ((0, rp - rows), (0, dip - din)))
-    g2 = jnp.pad(g2, ((0, rp - rows), (0, djp - dout)))
-    c2 = jnp.pad(c2, ((0, rp - rows), (0, 0)))
-    nr = rp // bt
-    grid = (dip // bi, djp // bj, nr)
-    out = pl.pallas_call(
-        functools.partial(_kernel, nr=nr),
-        grid=grid,
+    dtype = jnp.promote_types(a.dtype, g.dtype)
+    di, dj, dt = tiles(t, din, dout, dtype)
+    bi = di if bi is None else min(bi, din)
+    bj = dj if bj is None else min(bj, dout)
+    bt = dt if bt is None else bt
+    tp = pl.cdiv(t, bt) * bt
+    if tp != t:
+        a = jnp.pad(a, ((0, 0), (0, tp - t), (0, 0)))
+        g = jnp.pad(g, ((0, 0), (0, tp - t), (0, 0)))
+    a2 = a.reshape(b * tp, din)
+    g2 = g.reshape(b * tp, dout)
+    isz = jnp.dtype(dtype).itemsize
+    vmem = (2 * bt * (bi + bj) * isz + 3 * bi * bj * 4 + bt * bi * isz)
+    return pl.pallas_call(
+        functools.partial(_kernel, per_example=tp // bt, dtype=dtype),
+        grid=(pl.cdiv(din, bi), pl.cdiv(dout, bj), b * tp // bt),
         in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((bt, bi), lambda i, j, r: (r, i)),
             pl.BlockSpec((bt, bj), lambda i, j, r: (r, j)),
-            pl.BlockSpec((bt, 1), lambda i, j, r: (r, 0)),
         ],
         out_specs=pl.BlockSpec((bi, bj), lambda i, j, r: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((dip, djp), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((bi, bj), jnp.float32)],
+        out_shape=jax.ShapeDtypeStruct((din, dout), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=max(32 << 20, vmem * 5 // 4)),
         interpret=interpret,
         name="clip_reduce",
-    )(a2, g2, c2)
-    return out[:din, :dout]
+    )(factors.astype(jnp.float32), a2, g2)

@@ -45,8 +45,8 @@ def ghost_norm_blocked_op(a, g, num_blocks: int, *, block_axis: str = "out",
 
 
 @partial(jax.jit, static_argnames=("bi", "bj", "bt"))
-def clip_reduce_op(a, g, factors, *, bi: int = 256, bj: int = 256,
-                   bt: int = 256):
+def clip_reduce_op(a, g, factors, *, bi: int | None = None,
+                   bj: int | None = None, bt: int | None = None):
     return clip_reduce(a, g, factors, bi=bi, bj=bj, bt=bt,
                        interpret=_interpret())
 

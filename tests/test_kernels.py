@@ -38,19 +38,51 @@ def test_ghost_norm_kernel(shape, dtype):
     np.testing.assert_allclose(got, want, rtol=rtol)
 
 
-@pytest.mark.parametrize("shape", SHAPES)
+# clip_reduce past SHAPES: din 200 (not a multiple of 128) with a head-like
+# dout of 128·13, and din 384 (128·3) with dout 130: no derived or explicit
+# tile divides them, so the last blocks are ragged; T = 40 and 100 are no
+# multiple of the explicit row tile, nor T = 100 of the derived one (the
+# zero-row pad).
+CLIP_SHAPES = SHAPES + [(2, 40, 200, 1664), (3, 100, 384, 130)]
+
+
+@pytest.mark.parametrize("shape", CLIP_SHAPES)
 @pytest.mark.parametrize("dtype", DTYPES)
 def test_clip_reduce_kernel(shape, dtype):
+    """Explicit 128 tiles and the derived ones, against the f32 oracle;
+    factors span 0 to 1, with the first example's at 0."""
     b, t, din, dout = shape
     key = jax.random.PRNGKey(hash(shape) & 0xFFF)
     a = jax.random.normal(key, (b, t, din)).astype(dtype)
     g = (jax.random.normal(jax.random.fold_in(key, 1), (b, t, dout)) * 0.1
          ).astype(dtype)
     f = jax.random.uniform(jax.random.fold_in(key, 2), (b,))
-    got = clip_reduce(a, g, f, bi=128, bj=128, bt=128, interpret=True)
+    if b > 1:
+        f = f.at[0].set(0.0).at[-1].set(1.0)
     want = ref.clip_reduce_ref(a, g, f)
     rtol = 4e-3 if dtype == jnp.bfloat16 else 1e-4
-    np.testing.assert_allclose(got, want, rtol=rtol, atol=1e-4)
+    for tiles in (dict(bi=128, bj=128, bt=128), {}):
+        got = clip_reduce(a, g, f, **tiles, interpret=True)
+        np.testing.assert_allclose(got, want, rtol=rtol, atol=1e-4,
+                                   err_msg=str(tiles))
+
+
+@pytest.mark.parametrize("bt", [None, 32])
+def test_clip_reduce_sensitivity_bound(bt):
+    """With c_b = C / ‖A_bᵀG_b‖, one example's clipped sum from bf16
+    operands has Frobenius norm at most C·(1 + 1e-5): the factor stays an
+    unquantized f32 that scales the f32 partial sums."""
+    b, t, din, dout, clip = 4, 96, 200, 300, 0.7
+    key = jax.random.PRNGKey(5)
+    a = jax.random.normal(key, (b, t, din)).astype(jnp.bfloat16)
+    g = jax.random.normal(jax.random.fold_in(key, 1), (b, t, dout)
+                          ).astype(jnp.bfloat16)
+    norms = jnp.sqrt(ref.ghost_norm_ref(a, g))
+    c = clip / norms
+    for i in range(b):
+        got = clip_reduce(a[i:i + 1], g[i:i + 1], c[i:i + 1], bt=bt,
+                          interpret=True)
+        assert float(jnp.linalg.norm(got)) <= clip * (1 + 1e-5), i
 
 
 @settings(max_examples=10, deadline=None)

@@ -11,6 +11,7 @@ import os
 import jax
 import jax.numpy as jnp
 import pytest
+from jax.extend import core as jex_core
 
 from repro.kernels.bk import scale_contract
 from repro.kernels.clip_reduce import clip_reduce
@@ -18,7 +19,7 @@ from repro.kernels.fused_clip import fused_norm_clip
 from repro.kernels.ghost_norm import ghost_norm, ghost_norm_blocked
 from repro.kernels.paged_attn import paged_attn
 
-B, T, D, FF = 4, 512, 2560, 9728
+B, T, D, FF, VOCAB = 4, 512, 2560, 9728, 151936
 KV, G, HD, PAGE = 8, 4, 128, 16
 PAGES = 4 * 43 + 1  # 4 slots x 688-token horizon, plus the trash page
 
@@ -68,6 +69,15 @@ CASES = {
                          _f32((B,))]),
     "clip_reduce": (lambda a, g, f: clip_reduce(a, g, f),
                     [_bf16((B, T, D)), _bf16((B, T, FF)), _f32((B,))]),
+    # derived tiles at the untied head (no tile divides 151936: a ragged
+    # last block, and the largest VMEM working set, 2560 x 2560) and at
+    # the fused gate+up (2432-wide blocks)
+    "clip_reduce.head": (lambda a, g, f: clip_reduce(a, g, f),
+                         [_bf16((B, T, D)), _bf16((B, T, VOCAB)),
+                          _f32((B,))]),
+    "clip_reduce.gate_up": (lambda a, g, f: clip_reduce(a, g, f),
+                            [_bf16((B, T, D)), _bf16((B, T, 2 * FF)),
+                             _f32((B,))]),
     "bk_scale_contract": (lambda a, g, f: scale_contract(a, g, f),
                           [_bf16((4, B, T, D)), _bf16((4, B, T, FF)),
                            _f32((4, B))]),
@@ -87,7 +97,8 @@ def test_kernel_compiles_for_v5e_at_qwen3_4b_widths(name, one_chip):
     kernel_lines = [ln for ln in text.splitlines()
                     if 'custom_call_target="tpu_custom_call"' in ln]
     assert kernel_lines, f"{name}: no Mosaic kernel in the compiled program"
-    assert any(f"{name}/pallas_call" in ln for ln in kernel_lines)
+    kernel = name.partition(".")[0]
+    assert any(f"{kernel}/pallas_call" in ln for ln in kernel_lines)
 
 
 def test_tiny_dp_step_phases_for_v5e(one_chip):
@@ -146,3 +157,77 @@ def test_tiny_dp_step_phases_for_v5e(one_chip):
         assert all(kernel in n for n in names), names
     assert {hlo.FORWARD, hlo.BACKWARD, hlo.NOISE_UPDATE} <= set(
         phases.values())
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in its params."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for p in eqn.params.values():
+            for sub in (p if isinstance(p, (tuple, list)) else (p,)):
+                if isinstance(sub, jex_core.ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, jex_core.Jaxpr):
+                    yield from _eqns(sub)
+
+
+def test_cell_step_clip_reduce_takes_bf16_unpadded(one_chip):
+    """The qwen3-4b cell's step (one layer: every linear shape of the cell
+    once) for a described v5e: each `clip_reduce` call takes the bf16
+    activations and gradients as they are, (B·T, din) and (B·T, dout), and
+    returns the (din, dout) f32 sum itself, so no pad of G and no slice of
+    the sum surround it, the untied head's included."""
+    import dataclasses
+    import re
+
+    from repro import optim
+    from repro.configs import get_config
+    from repro.core.dp_sgd import DPConfig, make_dp_train_step
+    from repro.core.spec import abstract_params
+    from repro.kernels import backend as KB
+    from repro.models.transformer import build_model
+
+    m = build_model(dataclasses.replace(get_config("qwen3-4b"), num_layers=1))
+    dpc = DPConfig(mode="per_layer", execution="bk", sigma=1.0,
+                   sampling_rate=B / 1024, steps=10, adaptive=True,
+                   backend="pallas", autotune=False)
+    init_fn, step_fn, _ = make_dp_train_step(
+        m.loss_fn, m.spec, m.layout, optim.adam(1e-3), dpc, batch_size=B)
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(on_chip, abstract_params(m.spec))
+    opt_abs, dp_abs = jax.tree_util.tree_map(
+        on_chip, jax.eval_shape(init_fn, params))
+    batch = {k: on_chip(jax.ShapeDtypeStruct((B, T), jnp.int32))
+             for k in ("tokens", "targets")}
+    key = on_chip(jax.ShapeDtypeStruct((2,), jnp.uint32))
+    with KB.scoped("pallas", interpret=False):
+        traced = jax.jit(step_fn).trace(params, opt_abs, dp_abs, batch, key)
+        text = traced.lower().compile().as_text()
+    # inside each kernel the MXU's dot takes the bf16 blocks as they are
+    dots = [[str(v.aval.dtype) for v in dot.invars]
+            for k in _eqns(traced.jaxpr.jaxpr)
+            if k.primitive.name == "pallas_call"
+            and str(k.params["name"]) == "clip_reduce"
+            for dot in _eqns(k.params["jaxpr"])
+            if dot.primitive.name == "dot_general"]
+    assert dots and all(d == ["bfloat16", "bfloat16"] for d in dots), dots
+    call = re.compile(
+        r"= f32\[(\d+),(\d+)\]\S* custom-call\(.*operand_layout_constraints="
+        r"\{f32\[4\]\{0\}, (\w+)\[(\d+),(\d+)\]\{1,0\}, "
+        r"(\w+)\[(\d+),(\d+)\]\{1,0\}\}")
+    shapes = []
+    for ln in text.splitlines():
+        if ('custom_call_target="tpu_custom_call"' not in ln
+                or not re.search(r"clip_reduce\)?/pallas_call", ln)):
+            continue
+        mt = call.search(ln)
+        assert mt, ln[:300]
+        din, dout, ta, ra, ca, tg, rg, cg = mt.groups()
+        assert (ta, tg) == ("bf16", "bf16"), ln[:300]
+        assert (ra, ca, rg, cg) == (str(B * T), din, str(B * T), dout)
+        shapes.append((int(din), int(dout)))
+    assert sorted(shapes) == sorted([(D, 48 * HD), (32 * HD, D),
+                                     (D, 2 * FF), (FF, D), (D, VOCAB)])
