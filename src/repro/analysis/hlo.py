@@ -738,6 +738,11 @@ _PHASE_SCOPE = re.compile(
 # JAX names a transposed op `transpose(jvp(...))/...`, and the backward of a
 # custom_vjp `transpose(<scope>)/jvp(<fn>)/...`
 _TRANSPOSE = re.compile(r"transpose\(")
+# clipping work on the backward's residuals that runs after the transposed
+# pass, not transposed itself: the BK epilogue's clipped sums
+# (core.bk.contract_clipped) and a tied group's cross term (core.bk.
+# TIED_CROSS), as the clipped sums and norms of per_layer are backward
+_AFTER_BACKWARD = re.compile(r"\b(bk_epilogue_contract|dp_tied_cross)\b")
 
 
 def _called(ins: Instr) -> list[str]:
@@ -759,7 +764,10 @@ def _named_phase(ins: Instr) -> str | None:
         # XLA fuses the noise's `g + z` into the optimizer's elementwise
         # fusion, and a fusion carries its root's op_name: one phase
         return NOISE_UPDATE
-    return BACKWARD if _TRANSPOSE.search(nm.group(1)) else FORWARD
+    name = nm.group(1)
+    if _TRANSPOSE.search(name) or _AFTER_BACKWARD.search(name):
+        return BACKWARD
+    return FORWARD
 
 
 def _phases(comps: dict[str, list[Instr]]) -> dict[str, str | None]:
@@ -812,7 +820,9 @@ def op_phases(text: str) -> dict[str, str | None]:
     forward       under the clip scope and not transposed
     backward      under the clip scope and transposed: the norms, the
                   clipped sums and forward ops rematerialised in the
-                  backward
+                  backward; and the clipping work on the backward's
+                  residuals after it (the BK epilogue's
+                  `bk_epilogue_contract`, a tied group's `dp_tied_cross`)
     noise_update  under the noise or the update scope
     None          outside every phase scope (see `_phases`)
     """

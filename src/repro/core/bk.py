@@ -37,9 +37,12 @@ Pipeline (driven by `core.clipping.dp_clipped_gradients`):
                          once per step (e.g. the MTP head) or shared-site
                          parameters (sensitivity_mult > 1), whose single
                          threshold leaf would sum residuals across sites.
-                         Any other probe failure raises.
+                         A tied embedding / LM head is the one leaf used
+                         twice that BK captures: its two uses merge into
+                         one `tied` recipe. Any other probe failure raises.
   2. `capture_clipped` — ONE `value_and_grad` over the channel tree:
-                         per-group norms² + cached residuals.
+                         per-group norms² + cached residuals; a tied
+                         group's norm² gains its cross term here.
   3. driver computes the per-example clip factors from the norms.
   4. `contract_clipped`— the epilogue: per layer, one scale-and-contract
                          over the cached residuals (`scale_contract` in
@@ -117,9 +120,24 @@ def _require_capture_scope(channel: BkChannel) -> None:
             "repro.core.bk.capture_clipped")
 
 
+# a tied table's channel carries the sinks of both its uses: the
+# embedding's (record_embed) and the head's (record_tied_head)
+_EMBED_SINKS = frozenset(("g", "ids"))
+_TIED_HEAD_SINKS = frozenset(("a", "gh", "kst"))
+
+
 def emit(channel: BkChannel, norms_sq, **sink_vals) -> BkChannel:
-    """Build the channel cotangent: norms² + residuals cast to sink dtypes."""
+    """Build the channel cotangent: norms² + residuals cast to sink dtypes.
+
+    Every call writes every sink of its channel, except on a tied group's:
+    there each use writes its own sinks and gives the other's a zero
+    cotangent, so the two cotangents add up to the whole residual set."""
     _require_capture_scope(channel)
+    have, want = frozenset(sink_vals), frozenset(channel.sink)
+    if (have != want and want == _EMBED_SINKS | _TIED_HEAD_SINKS
+            and have in (_EMBED_SINKS, _TIED_HEAD_SINKS)):
+        sink_vals = {k: sink_vals.get(k, jnp.zeros(s.shape, s.dtype))
+                     for k, s in channel.sink.items()}
     sink_ct = jax.tree_util.tree_map(
         lambda s, v: v.astype(s.dtype), channel.sink, dict(sink_vals))
     return BkChannel(norms_sq.astype(jnp.float32), sink_ct, channel.group)
@@ -135,7 +153,7 @@ class Recipe:
     """What one dp-primitive call site stashes for one clipping group."""
 
     kind: str          # linear|linear_blocked|embed|scale|shift|broadcast|
-    #                    lora|expert|expert_grouped
+    #                    lora|expert|expert_grouped|tied (embed + tied_head)
     c_ndim: int        # rank of the PER-CALL threshold (after scan slicing)
     sinks: dict        # sink name -> ShapeDtypeStruct (per-call shapes)
     extras: dict       # kind-specific statics (has_bias, vocab, ...)
@@ -162,7 +180,14 @@ def _record(channel, kind, sinks, **extras):
         return
     name = channel.group
     if name in rec:
-        rec[name].count += 1
+        prev = rec[name]
+        if {prev.kind, kind} == {"embed", "tied_head"}:
+            # a tied table: the lookup and the head are one group's two uses
+            prev.kind = "tied"
+            prev.sinks = {**prev.sinks, **sinks}
+            prev.extras = {**prev.extras, **extras}
+            return
+        prev.count += 1
         return
     rec[name] = Recipe(kind, channel.c.ndim, sinks, extras)
 
@@ -206,6 +231,19 @@ def record_embed(c, table, ids):
                          # exact for vocab < 2^24
                          "ids": SDS((bsz, tf), jnp.float32)},
             vocab=table.shape[0])
+
+
+def record_tied_head(c, table, x, ids):
+    if _RECORDER.get() is None or not isinstance(c, BkChannel):
+        return
+    bsz, tf, d, vocab = x.shape[0], _tfold(x), x.shape[-1], table.shape[0]
+    gdt = jnp.result_type(x.dtype, table.dtype)
+    _record(c, "tied_head", {"a": SDS((bsz, tf, d), x.dtype),
+                             "gh": SDS((bsz, tf, vocab), gdt),
+                             # gl[b, s, ids[b, t]]: the head's logit
+                             # gradient at the example's own tokens
+                             "kst": SDS((bsz, tf, ids.shape[-1]), gdt)},
+            vocab=vocab)
 
 
 def record_scale(c, s, xhat):
@@ -262,22 +300,36 @@ def record_expert_grouped(c, w, x):
 
 def probe_recipes(loss_fn, params, batch, layout: GroupLayout,
                   batch_size: int) -> dict | None:
-    """Discover per-group residual shapes; None when BK cannot apply."""
+    """Discover per-group residual shapes; None when BK cannot apply. A
+    tied group is captured or refused here, never left to the twopass
+    fallback, whose norms would leave out the cross term of its uses."""
+    rec, why = None, None
     if any(g.sensitivity_mult > 1 for g in layout.groups):
         # shared-site params (e.g. Zamba2's shared attention block): one
         # threshold leaf is consumed at several runtime sites inside a scan,
         # so sink cotangents would SUM residuals across sites — invalid.
-        return None
-    inf_tree = layout.pack_value(jnp.inf, batch_size)
-    probe = {k: BkChannel(v, None, k) for k, v in inf_tree.items()}
-    # any other failure to trace with channel leaves is a bug (in a model
-    # that handles thresholds as raw arrays, or in a record_* recorder):
-    # it raises rather than silently doubling the backward passes
-    with _recording() as rec:
-        jax.eval_shape(lambda p, b, t: jnp.sum(loss_fn(p, b, t)),
-                       params, batch, probe)
-    if any(r.count > 1 for r in rec.values()):
-        return None  # one leaf, several call sites (e.g. MTP reuses head)
+        why = "shared-site parameters (sensitivity_mult > 1)"
+    else:
+        inf_tree = layout.pack_value(jnp.inf, batch_size)
+        probe = {k: BkChannel(v, None, k) for k, v in inf_tree.items()}
+        # any other failure to trace with channel leaves is a bug (in a
+        # model that handles thresholds as raw arrays, or in a record_*
+        # recorder): it raises rather than silently doubling the backward
+        # passes
+        with _recording() as rec:
+            jax.eval_shape(lambda p, b, t: jnp.sum(loss_fn(p, b, t)),
+                           params, batch, probe)
+        if any(r.count > 1 for r in rec.values()):
+            # one leaf, several call sites (e.g. MTP reuses head)
+            rec, why = None, "a leaf consumed at several call sites"
+    for name in layout.tied_groups:
+        r = rec.get(name) if rec is not None else why
+        if not (isinstance(r, Recipe) and r.kind == "tied"
+                and r.c_ndim == inf_tree[name].ndim):
+            raise ValueError(
+                f"tied group {name!r}: BK captures a tied table as one "
+                "unstacked embedding lookup plus one dp_tied_head per step, "
+                f"found {r}")
     return rec
 
 
@@ -308,9 +360,29 @@ def build_channels(layout: GroupLayout, recipes: dict, batch_size: int):
     return out
 
 
+TIED_CROSS = "dp_tied_cross"  # named scope of the tied groups' cross term
+
+
+def tied_cross(g, a, kst):
+    """(B,) cross term Σ_{t,s} (g_t · a_s) kst[s, t] of a tied group: the
+    inner product of one example's embedding gradient (gy rows g_t into
+    rows ids_t) with its head gradient (Σ_s gl_s a_sᵀ), kst[s, t] =
+    gl_s[ids_t]. H_s = Σ_t kst[s, t] g_t takes O(T² d) on the MXU with the
+    operands in their own dtype; the last product sums in f32."""
+    h = jnp.einsum("bst,btd->bsd", kst, g.astype(kst.dtype),
+                   preferred_element_type=jnp.float32)
+    return jnp.sum(a.astype(jnp.float32) * h, axis=(1, 2))
+
+
 def capture_clipped(loss_fn, params, batch, layout: GroupLayout,
                     batch_size: int):
-    """One backprop: (sum loss, (K, B) norms², residuals, recipes) or None."""
+    """One backprop: (sum loss, (K, B) norms², residuals, recipes, (B,)
+    tied cross term) or None.
+
+    A tied group's norm² comes out of the backward as ‖G_e‖² + ‖G_h‖², the
+    two uses' own; twice their cross term is added here, so the norm the
+    factors see is the exact ‖G_e + G_hᵀ‖². The cross term returned is
+    summed over the tied groups (zeros without one)."""
     recipes = probe_recipes(loss_fn, params, batch, layout, batch_size)
     if recipes is None:
         return None
@@ -326,10 +398,17 @@ def capture_clipped(loss_fn, params, batch, layout: GroupLayout,
         val, grads = jax.value_and_grad(f)(channels)
     norm_tree = {k: (v.c if isinstance(v, BkChannel) else v)
                  for k, v in grads.items()}
-    norms = layout.unpack(norm_tree)
     residuals = {k: v.sink for k, v in grads.items()
                  if isinstance(v, BkChannel)}
-    return val, norms, residuals, recipes
+    cross = jnp.zeros((batch_size,), jnp.float32)
+    for name in layout.tied_groups:
+        sink = residuals[name]
+        with jax.named_scope(TIED_CROSS):
+            c = tied_cross(sink["g"], sink["a"], sink["kst"])
+            norm_tree[name] = norm_tree[name] + 2.0 * c
+        cross = cross + c
+    norms = layout.unpack(norm_tree)
+    return val, norms, residuals, recipes, cross
 
 
 # ---------------------------------------------------------------------------
@@ -403,6 +482,16 @@ def _leaf_grad(layout, recipes, residuals, f_rows, node: P, path, eng):
         )(ids4, g4, f2)
         return dt.reshape(node.shape).astype(node.dtype)
 
+    if kind == "tied":
+        # the head's Σ_i f_i G_iᵀ A_i lands in the table's (V, d) layout
+        # with no transposed copy; the embedding's clipped rows are
+        # scattered into it in place (probe_recipes: unstacked, f is (B,))
+        out = eng.scale_contract(sink["gh"], sink["a"], f)
+        ids = jnp.round(sink["ids"]).astype(jnp.int32).reshape(-1)
+        rows = (sink["g"].astype(jnp.float32) * f[:, None, None]
+                ).reshape(-1, node.shape[-1])
+        return out.at[ids].add(rows).astype(node.dtype)
+
     if kind in ("scale", "shift", "broadcast"):
         pg = sink["pg"]  # prefix + (B,) + per-call param shape
         lead = pg.ndim - (1 + per_elem)
@@ -466,7 +555,8 @@ def contract_clipped(layout: GroupLayout, recipes: dict, residuals: dict,
         return {k: build(v, path + (k,)) for k, v in node.items()}
 
     if psum_axes is None:
-        return build(layout._spec, ())
+        with jax.named_scope("bk_epilogue_contract"):
+            return build(layout._spec, ())
 
     leaves: list[tuple[tuple, P]] = []
 

@@ -30,7 +30,9 @@ explicit `ghost_flat_twopass` / `per_group_twopass` reference modes):
   bk      : one backprop + epilogue (above). Falls back to twopass
             automatically when the layout cannot be captured (a threshold
             leaf consumed at >1 call sites, shared-site params with
-            sensitivity_mult > 1 — see bk.probe_recipes).
+            sensitivity_mult > 1 — see bk.probe_recipes). A tied embedding
+            / LM head is captured exactly and never falls back: only BK
+            sees the cross term of its two uses (`check_tied_mode`).
   twopass : the historical reference — pass 1 reads norms² only (weight
             contractions dead-code-eliminated), pass 2 applies the
             per-example factor via direct-scale thresholds.
@@ -68,6 +70,60 @@ class ClipResult(NamedTuple):
     grads: Any            # pytree like params: clipped summed grads
     norms_sq: jax.Array   # (K, B) per-group per-example squared norms
     loss: jax.Array       # scalar mean per-example loss (pre-clipping)
+    tied_cross: Any = None  # (B,) the tied groups' cross term, already
+    #   inside norms_sq (zeros without a tied group or off the BK capture)
+
+
+def check_tied_mode(layout: GroupLayout, mode: str, execution: str, *,
+                    trainable_key: str | None = None,
+                    sharded: bool = False) -> None:
+    """Refuse, before anything is traced, a mode that cannot clip a tied
+    embedding / LM head group (GroupLayout.tied_groups) by its exact
+    per-example norm ‖G_e + G_hᵀ‖². The step factory and both clipping
+    entry points call it; bk.probe_recipes refuses a tied layout that BK
+    cannot capture.
+
+    ghost_flat / per_group through BK see both uses' residuals and add the
+    cross term (core.bk); non_private needs no norm; naive_flat
+    differentiates each example's loss by the leaf itself, so its norm is
+    exact. The others would clip with the per-use norms ‖G_e‖² + ‖G_h‖²,
+    which leave the cross term out and misstate the sensitivity."""
+    if not layout.tied_groups:
+        return
+    why = tied_refusal(mode, execution, sharded=sharded)
+    if (why is None and base_mode(mode) in ("ghost_flat", "per_group")
+            and not _bk_capture_ok(layout, trainable_key)):
+        why = (f"BK cannot capture a layout that is not the trainable tree "
+               f"{trainable_key!r}")
+    if why is not None:
+        raise ValueError(
+            f"clipping mode {mode!r} (execution {execution!r}) cannot clip "
+            f"the tied group(s) {list(layout.tied_groups)} by their exact "
+            f"norm: {why}. Use ghost_flat or per_group with execution='bk' "
+            "on one device.")
+
+
+def tied_refusal(mode: str, execution: str, *,
+                 sharded: bool = False) -> str | None:
+    """Why `mode` cannot clip a tied group by its exact norm; None if it
+    can (see check_tied_mode)."""
+    if mode.endswith("_twopass"):
+        mode, execution = base_mode(mode), "twopass"
+    if mode in ("non_private", "naive_flat"):
+        return None
+    if mode in ("ghost_flat", "per_group"):
+        if execution != "bk":
+            return ("its norms-only pass returns each use's norm alone, "
+                    "without the cross term of the two")
+        if sharded:
+            return ("the sharded step has no tested tied path (the cross "
+                    "term per model shard, the tied epilogue's psum)")
+        return None
+    if mode == "per_layer":
+        return ("it clips each use inside the backward, where the head's "
+                "group norm would need the embedding's part, which arrives "
+                "last")
+    return "it has no exact norm for a leaf used twice"
 
 
 def _sum_loss(loss_fn: LossFn, params, batch, thresholds) -> jax.Array:
@@ -152,16 +208,20 @@ def _norms_pass(loss_fn, params, batch, layout, batch_size, inf_tree,
     """The shared first stage of ghost_flat / per_group: one backward pass
     for (sum loss, (K, B) norms²), capturing BK residuals when possible.
 
-    Returns (val, norms, cap) with cap = (residuals, recipes) under BK or
-    None when running (or falling back to) the twopass reference."""
+    Returns (val, norms, cap, cross) with cap = (residuals, recipes) under
+    BK or None when running (or falling back to) the twopass reference, and
+    cross the (B,) tied cross term (zeros off BK). A tied layout never falls
+    back (check_tied_mode, bk.probe_recipes): the twopass norms leave the
+    cross term out."""
     cap = (bk.capture_clipped(loss_fn, params, batch, layout, batch_size)
            if execution == "bk" and _bk_capture_ok(layout, trainable_key)
            else None)
     if cap is not None:
-        val, norms, residuals, recipes = cap
-        return val, norms, (residuals, recipes)
+        val, norms, residuals, recipes, cross = cap
+        return val, norms, (residuals, recipes), cross
     val, norm_tree = _norms_only(loss_fn, params, batch, inf_tree)
-    return val, layout.unpack(norm_tree), None
+    return (val, layout.unpack(norm_tree), None,
+            jnp.zeros((batch_size,), jnp.float32))
 
 
 def _naive_group_norms(layout: GroupLayout, jac: Any, batch_size: int
@@ -218,15 +278,17 @@ def dp_clipped_gradients(
         raise ValueError(f"mode {mode!r} not in {MODES}")
     if execution not in EXECUTIONS:
         raise ValueError(f"execution {execution!r} not in {EXECUTIONS}")
+    check_tied_mode(layout, mode, execution, trainable_key=trainable_key)
     if mode.endswith("_twopass"):
         mode, execution = base_mode(mode), "twopass"
     inf_tree = layout.pack_value(jnp.inf, batch_size)
+    no_cross = jnp.zeros((batch_size,), jnp.float32)
 
     if mode == "non_private":
         val, grads = _grads_only(loss_fn, params, batch, inf_tree,
                                  trainable_key)
         norms = jnp.zeros((layout.num_groups, batch_size), jnp.float32)
-        return ClipResult(grads, norms, val / batch_size)
+        return ClipResult(grads, norms, val / batch_size, no_cross)
 
     if mode == "per_layer":
         if thresholds is None:
@@ -235,12 +297,12 @@ def dp_clipped_gradients(
         val, grads, norm_tree = _grads_and_norms(loss_fn, params, batch,
                                                  th_tree, trainable_key)
         norms = layout.unpack(norm_tree)
-        return ClipResult(grads, norms, val / batch_size)
+        return ClipResult(grads, norms, val / batch_size, no_cross)
 
     if mode == "ghost_flat":
-        val, norms, cap = _norms_pass(loss_fn, params, batch, layout,
-                                      batch_size, inf_tree, trainable_key,
-                                      execution)
+        val, norms, cap, cross = _norms_pass(loss_fn, params, batch, layout,
+                                             batch_size, inf_tree,
+                                             trainable_key, execution)
         total = jnp.sum(norms, axis=0)  # (B,)
         f = flat_clip_factors(total, flat_threshold)  # (B,)
         if cap is not None:  # BK epilogue: contract the cached residuals
@@ -252,14 +314,14 @@ def dp_clipped_gradients(
             scale_tree = layout.pack_value(-f, batch_size)
             _, grads = _grads_only(loss_fn, params, batch, scale_tree,
                                    trainable_key)
-        return ClipResult(grads, norms, val / batch_size)
+        return ClipResult(grads, norms, val / batch_size, cross)
 
     if mode == "per_group":
         if group_assignment is None or group_thresholds is None:
             raise ValueError("per_group mode needs group_assignment + group_thresholds")
-        val, norms, cap = _norms_pass(loss_fn, params, batch, layout,
-                                      batch_size, inf_tree, trainable_key,
-                                      execution)
+        val, norms, cap, cross = _norms_pass(loss_fn, params, batch, layout,
+                                             batch_size, inf_tree,
+                                             trainable_key, execution)
         num_super = group_thresholds.shape[0]
         super_norms = jax.ops.segment_sum(
             norms, group_assignment, num_segments=num_super)  # (G, B)
@@ -273,7 +335,7 @@ def dp_clipped_gradients(
             scale_tree = layout.pack_rows(-f_per_layer)
             _, grads = _grads_only(loss_fn, params, batch, scale_tree,
                                    trainable_key)
-        return ClipResult(grads, norms, val / batch_size)
+        return ClipResult(grads, norms, val / batch_size, cross)
 
     # naive_flat: the Opacus-style materializing oracle.
     if trainable_key is None:
@@ -302,7 +364,7 @@ def dp_clipped_gradients(
         jac,
     )
     loss = jnp.mean(per_example_losses(params))
-    return ClipResult(grads, norms, loss)
+    return ClipResult(grads, norms, loss, no_cross)
 
 
 # ---------------------------------------------------------------------------
@@ -365,6 +427,8 @@ def sharded_clipped_gradients(
     execution: str = "bk",
 ) -> ShardedClipResult:
     """`dp_clipped_gradients` under manual SPMD — see module comment above."""
+    check_tied_mode(layout, mode, execution, trainable_key=trainable_key,
+                    sharded=True)
     if mode.endswith("_twopass"):
         mode, execution = base_mode(mode), "twopass"
     all_axes = tuple(data_axes) + (model_axis,)
@@ -402,8 +466,9 @@ def sharded_clipped_gradients(
     if shard_assignment is None:
         raise ValueError("sharded flat/group modes need shard_assignment")
 
-    val, norms, cap = _norms_pass(loss_fn, params, batch, layout, batch_size,
-                                  inf_tree, trainable_key, execution)
+    val, norms, cap, _ = _norms_pass(loss_fn, params, batch, layout,
+                                     batch_size, inf_tree, trainable_key,
+                                     execution)
     midx = jax.lax.axis_index(model_axis)
     own = (shard_assignment == midx).astype(jnp.float32)  # (K,)
     # this shard's contribution: norms² of the groups it owns only

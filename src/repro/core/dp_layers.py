@@ -207,6 +207,61 @@ dp_embed.defvjp(_dp_embed_fwd, _dp_embed_bwd)
 
 
 # ---------------------------------------------------------------------------
+# dp_tied_head: logits = x @ tableᵀ, the LM head reading the embedding table.
+#
+# The table is one leaf used twice, so one example's gradient of it is the
+# embedding's scatter G_e (rows ids_t <- gy_t) plus the head's G_hᵀ =
+# Σ_s gl_s x_sᵀ, and its exact squared norm is
+#     ‖G_e‖² + ‖G_h‖² + 2 Σ_{t,s} (gy_t · x_s) gl_s[ids_t].
+# The head's backward keeps the last factor, kst[b, s, t] = gl[b, s,
+# ids[b, t]], for the BK capture, which adds the cross term once the
+# embedding's gy is known (bk.capture_clipped).
+# ---------------------------------------------------------------------------
+
+
+@jax.custom_vjp
+def dp_tied_head(table: jax.Array, x: jax.Array, ids: jax.Array,
+                 c: jax.Array) -> jax.Array:
+    """table (V, d), x (B, T, d), ids (B, T) the tokens the same examples'
+    embedding looked up; c: the embedding group's threshold leaf."""
+    bk.record_tied_head(c, table, x, ids)
+    return jnp.einsum("btd,vd->btv", x, table)
+
+
+def _dp_tied_head_fwd(table, x, ids, c):
+    return dp_tied_head(table, x, ids, c), (table, x, ids, c)
+
+
+def _dp_tied_head_bwd(res, gy):
+    table, x, ids, c = res
+    eng = backend.active()
+    dx = jnp.einsum("btv,vd->btd", gy, table)
+    bsz = x.shape[0]
+    a3 = x.reshape(bsz, -1, x.shape[-1])
+    g3 = gy.reshape(bsz, -1, gy.shape[-1])
+    if isinstance(c, bk.BkChannel):
+        n = eng.linear_norms_sq(a3, g3)
+        with jax.named_scope(bk.TIED_CROSS):
+            # one column of S rows per token, the example's ids shared by
+            # every row: an index per element (take_along_axis) reads ~100x
+            # the bytes (21.7 GB against 0.22 GB at B=2, T=2048, V=122,753)
+            kst = jax.vmap(lambda gb, ib: jnp.take(gb, ib, axis=1))(
+                g3, ids.reshape(bsz, -1))
+        dc = bk.emit(c, n, a=a3, gh=g3, kst=kst)
+        return (jnp.zeros_like(table), dx, _int_zero_cotangent(ids), dc)
+    # Σ_i f_i G_iᵀ A_i lands in the table's (V, d) layout; its norms are
+    # those of A_iᵀ G_i. This clips the head's use alone, sound only at
+    # c = +inf: clipping.check_tied_mode refuses every mode that would
+    # arrive here with a finite threshold, leaving non_private and the
+    # materializing naive_flat
+    n, _, dt = eng.linear_clip(g3, a3, c)
+    return dt.astype(table.dtype), dx, _int_zero_cotangent(ids), n
+
+
+dp_tied_head.defvjp(_dp_tied_head_fwd, _dp_tied_head_bwd)
+
+
+# ---------------------------------------------------------------------------
 # dp_scale / dp_shift: elementwise gain / bias parameters (norm layers).
 # ---------------------------------------------------------------------------
 

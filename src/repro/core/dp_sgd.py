@@ -19,7 +19,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import accounting, noise as noise_lib
-from repro.core.clipping import LossFn, base_mode, dp_clipped_gradients
+from repro.core.clipping import (LossFn, base_mode, check_tied_mode,
+                                 dp_clipped_gradients)
 from repro.kernels import backend as ghost_backend
 from repro.core.quantile import QuantileState, clip_counts, init_quantile_state, update_thresholds
 from repro.core.spec import GroupLayout, P, SpecTree, _walk, stable_hash
@@ -132,6 +133,10 @@ class StepMetrics(NamedTuple):
     clip_fraction: jax.Array  # mean over groups of fraction clipped
     mean_threshold: jax.Array
     grad_norm: jax.Array  # norm of the (noised, averaged) update direction
+    # what the step clipped with (single-device step; None under a mesh):
+    norms_sq: Any = None  # (K, B) per-group per-example squared norms
+    tied_cross: Any = None  # (B,) the tied groups' cross term, inside
+    #   norms_sq (zeros without a tied group or off the BK capture)
 
 
 # Named scopes of the step's phases: every op of a step lies under exactly
@@ -303,7 +308,7 @@ def _effective_thresholds(cfg: DPConfig, plan: DPPlan, dp_state: DPState):
 
 def _apply_update(cfg: DPConfig, plan: DPPlan, optimizer, trainable_key,
                   batch_size, params, opt_state, dp_state, noised, counts,
-                  thresholds, loss, k_q):
+                  thresholds, loss, k_q, norms_sq=None, tied_cross=None):
     """Post-clipping tail shared by the single-device and sharded steps:
     gradient averaging, optimizer update, private quantile update, metrics.
     `noised` must be the (noised) SUMMED clipped grads over the full batch;
@@ -332,6 +337,8 @@ def _apply_update(cfg: DPConfig, plan: DPPlan, optimizer, trainable_key,
         clip_fraction=1.0 - jnp.mean(counts) / batch_size,
         mean_threshold=jnp.mean(thresholds),
         grad_norm=gn,
+        norms_sq=norms_sq,
+        tied_cross=tied_cross,
     )
     return new_params, new_opt_state, new_dp_state, metrics
 
@@ -381,6 +388,9 @@ def make_dp_train_step(
     launch.sharding params_shardings as in_shardings to keep the weights
     STORED model-sharded between steps).
     """
+    # a tied embedding / LM head trains only where its exact norm exists
+    check_tied_mode(layout, cfg.mode, cfg.execution,
+                    trainable_key=trainable_key, sharded=mesh is not None)
     if cfg.private:
         # static PRNG-safety gate (see noise.check_leaf_key_collisions):
         # two leaf paths crc32-folding to the same key would draw
@@ -408,7 +418,8 @@ def make_dp_train_step(
     execution = "twopass" if cfg.mode.endswith("_twopass") else cfg.execution
 
     def _clip(params, batch, thresholds):
-        """Clipped sums + norms, accumulated over microbatches (exact)."""
+        """Clipped sums + norms + tied cross term, accumulated over
+        microbatches (exact)."""
         def one(batch_mb):
             if mode == "non_private":
                 return dp_clipped_gradients(
@@ -448,19 +459,22 @@ def make_dp_train_step(
             g_acc, loss_acc = acc
             g_acc = jax.tree_util.tree_map(
                 lambda a, g: a + g.astype(jnp.float32), g_acc, res.grads)
-            return (g_acc, loss_acc + res.loss), res.norms_sq
+            return (g_acc, loss_acc + res.loss), (res.norms_sq,
+                                                  res.tied_cross)
 
         tp = params if trainable_key is None else {
             trainable_key: params[trainable_key]}
         g0 = jax.tree_util.tree_map(
             lambda x: jnp.zeros(x.shape, jnp.float32), tp)
-        (g_sum, loss_sum), norms = jax.lax.scan(body, (g0, 0.0), split)
+        (g_sum, loss_sum), (norms, cross) = jax.lax.scan(body, (g0, 0.0),
+                                                         split)
         norms = jnp.moveaxis(norms, 0, 1).reshape(layout.num_groups,
                                                   batch_size)
         from repro.core.clipping import ClipResult
         g_sum = jax.tree_util.tree_map(
             lambda a, x: a.astype(x.dtype), g_sum, tp)
-        return ClipResult(g_sum, norms, loss_sum / nmb)
+        return ClipResult(g_sum, norms, loss_sum / nmb,
+                          cross.reshape(batch_size))
 
     def step_fn(params, opt_state, dp_state, batch, key):
         # scoped (not global) engine: the jitted trace of this function
@@ -499,7 +513,8 @@ def make_dp_train_step(
         with jax.named_scope(PHASE_UPDATE):
             return _apply_update(cfg, plan, optimizer, trainable_key,
                                  batch_size, params, opt_state, dp_state,
-                                 noised, counts, thresholds, res.loss, k_q)
+                                 noised, counts, thresholds, res.loss, k_q,
+                                 res.norms_sq, res.tied_cross)
 
     return init_fn, step_fn, plan
 

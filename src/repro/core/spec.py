@@ -57,6 +57,10 @@ class P:
     sensitivity_mult: float = 1.0  # >1 for params SHARED across use sites
     #   (each site clips to C_k separately; the summed contribution of one
     #   example is bounded by n_sites * C_k, which noise calibration must use)
+    tied: bool = False  # the leaf is BOTH the input embedding and the LM
+    #   head (dp_embed + dp_tied_head): one example's gradient is the sum of
+    #   the two uses', so its group's exact norm carries their cross term,
+    #   which only the BK capture computes (core.bk, core.clipping)
 
 
 SpecTree = Any  # nested dict[str, P | SpecTree]
@@ -149,8 +153,11 @@ class GroupLayout:
     def __init__(self, spec: SpecTree):
         groups: dict[str, dict] = {}
         leaf_group: dict[tuple[str, ...], str] = {}
+        tied: set[str] = set()
         for path, p in _walk(spec):
             gname = _group_path(path, p)
+            if p.tied:
+                tied.add(gname)
             stack_shape = tuple(p.shape[: p.stack])
             if p.blocks > 1:
                 stack_shape = stack_shape + (p.blocks,)
@@ -181,6 +188,8 @@ class GroupLayout:
             self._by_name[name] = grp
             offset += grp.count
         self.num_groups = offset
+        # groups holding a tied embedding / LM head leaf (see P.tied)
+        self.tied_groups: tuple[str, ...] = tuple(sorted(tied))
         self._leaf_group = leaf_group
         self._spec = spec
 

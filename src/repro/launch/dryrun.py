@@ -37,6 +37,7 @@ import numpy as np
 
 from repro import optim
 from repro.configs import ARCH_IDS, get_config
+from repro.core.clipping import tied_refusal
 from repro.core.dp_sgd import DPConfig, make_dp_train_step
 from repro.core.spec import abstract_params
 from repro.launch import inputs as I
@@ -260,11 +261,17 @@ def run_one(arch: str, shape_name: str, mesh_kind: str, *,
             audit: bool = False,
             tag: str = "") -> dict:
     shape = _shape_for(shape_name, debug)
+    reason = None
     if shape_name == "long_500k" and arch not in LONG_OK:
+        reason = ("full-attention arch; long_500k requires sub-quadratic "
+                  "attention (DESIGN.md)")
+    elif shape.kind == "train" and get_config(arch).tie_embeddings:
+        why = tied_refusal(clipping, execution, sharded=sharded)
+        if why is not None:
+            reason = f"tied embedding, which {clipping} cannot clip: {why}"
+    if reason is not None:
         result = {"arch": arch, "shape": shape_name, "mesh": mesh_kind,
-                  "status": "skipped",
-                  "reason": "full-attention arch; long_500k requires "
-                            "sub-quadratic attention (DESIGN.md)"}
+                  "status": "skipped", "reason": reason}
         if save:
             os.makedirs(RESULTS_DIR, exist_ok=True)
             with open(os.path.join(

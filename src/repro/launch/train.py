@@ -304,7 +304,10 @@ def run(argv=None) -> TrainRun:
         # initial state: place every argument where the program expects it
         state = jax.device_put(state, compiled.input_shardings[0])
         params, opt_state, dp_state, met = compiled(*state)
-        met = {k: float(v) for k, v in jax.device_get(met)._asdict().items()}
+        # the scalar readings only: the per-example norms stay on the device
+        met = {k: float(v) for k, v in jax.device_get(
+            met._replace(norms_sq=None, tied_cross=None))._asdict().items()
+            if v is not None}
         step_s.append(time.perf_counter() - t0)
         metrics.append(met)
         if i % args.log_every == 0 or i == args.steps - 1:

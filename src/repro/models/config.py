@@ -8,6 +8,7 @@ MoE (token-choice top-k, shared experts, MLA), SSM (Mamba2, RWKV6), hybrids
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any
 
 import jax.numpy as jnp
@@ -67,8 +68,21 @@ class ModelConfig:
     m_rope_sections: tuple[int, ...] = (16, 24, 24)  # (t, h, w) of head_dim/2
     # ----- MTP (DeepSeek-V3 multi-token prediction) -----
     mtp_depth: int = 0
+    # ----- muP (MiniCPM, arXiv:2404.06395) -----
+    # x0 = scale_emb * E[ids]; each residual branch is multiplied by
+    # scale_depth / sqrt(mup_depth); the LM head reads h / logit_divisor,
+    # logit_divisor = d_model / dim_model_base. mup_depth is the PUBLISHED
+    # depth, so a depth cut (a pipeline stage) keeps the multiplier.
+    scale_emb: float = 1.0
+    scale_depth: float = 1.0
+    mup_depth: int = 0  # 0 => residual multiplier 1
+    dim_model_base: int = 0  # 0 => logit divisor 1
     # ----- misc -----
     max_seq_len: int = 131_072
+    # one (vocab, d) matrix is the input embedding AND the LM head (head
+    # logits = h @ Eᵀ); its clipping group's exact per-example norm needs
+    # the cross term of the two uses (core.bk), so only BK execution of the
+    # flat / group modes trains it privately
     tie_embeddings: bool = False
     dtype: Any = jnp.float32
     norm_eps: float = 1e-5
@@ -90,6 +104,18 @@ class ModelConfig:
         return self.d_model // max(self.num_heads, 1)
 
     @property
+    def residual_multiplier(self) -> float:
+        if not self.mup_depth:
+            return 1.0
+        return self.scale_depth / math.sqrt(self.mup_depth)
+
+    @property
+    def logit_divisor(self) -> float:
+        if not self.dim_model_base:
+            return 1.0
+        return self.d_model / self.dim_model_base
+
+    @property
     def has_attention(self) -> bool:
         return self.attention_kind != "none" and self.num_heads > 0
 
@@ -109,13 +135,21 @@ class ModelConfig:
             assert self.moe_d_ff > 0
         if self.arch_type == "audio":
             assert self.encoder_layers > 0
+        mup = (self.scale_emb != 1.0 or self.residual_multiplier != 1.0
+               or self.logit_divisor != 1.0)
+        if (self.tie_embeddings or mup) and (
+                self.arch_type != "dense" or self.mtp_depth
+                or self.lora_rank or self.num_experts):
+            raise ValueError(
+                f"{self.name}: tied embeddings and the muP scalars are "
+                "implemented for the dense decoder without MTP or DP-LoRA")
 
     def param_count(self) -> int:
         """Exact dense-equivalent parameter count from the spec (filled in by
         models.transformer at build time); here: rough analytic estimate."""
         d, f, v, l = self.d_model, self.d_ff, self.vocab_size, self.num_layers
         per_layer = 4 * d * d + 3 * d * f
-        return l * per_layer + 2 * v * d
+        return l * per_layer + (1 if self.tie_embeddings else 2) * v * d
 
 
 @dataclasses.dataclass(frozen=True)

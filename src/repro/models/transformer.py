@@ -86,6 +86,31 @@ def _rwkv_block_spec(cfg: ModelConfig, n: int) -> dict:
 # ---------------------------------------------------------------------------
 
 
+def _branch(cfg, y):
+    """A residual branch's output times muP's depth multiplier, where the
+    config sets one (MiniCPM: scale_depth / sqrt(published depth))."""
+    s = cfg.residual_multiplier
+    return y if s == 1.0 else y * s
+
+
+def _embed(cfg, params, tokens, th):
+    """Input embeddings, times muP's scale_emb where the config sets one."""
+    x = dpl.dp_embed(params["embed"]["w"], tokens, th["embed"])
+    return x if cfg.scale_emb == 1.0 else x * cfg.scale_emb
+
+
+def _lm_head(cfg, params, x, th, ids):
+    """Logits from final-normed hidden states x (B, T, d), divided first by
+    muP's logit divisor where the config sets one. A tied config reads the
+    embedding table as the head (dp_tied_head, in the embedding's clipping
+    group); `ids` (B, T) are the tokens the same rows embedded."""
+    if cfg.logit_divisor != 1.0:
+        x = x / cfg.logit_divisor
+    if cfg.tie_embeddings:
+        return dpl.dp_tied_head(params["embed"]["w"], x, ids, th["embed"])
+    return dpl.dp_linear(params["head"]["w"], None, x, th["head"])
+
+
 def _apply_attn_block(cfg, params, x, th, positions, *, causal=True,
                       window=None, enc_out=None, moe_layer=False,
                       lora=None, lora_th=None):
@@ -98,7 +123,7 @@ def _apply_attn_block(cfg, params, x, th, positions, *, causal=True,
         att = A.gqa_attention(cfg, params["attn"], h, subth(th, "attn"),
                               positions, causal=causal, window=window,
                               lora=lora, lora_th=lora_th)
-    x = x + att
+    x = x + _branch(cfg, att)
     aux = jnp.zeros((x.shape[0],), jnp.float32)
     if enc_out is not None:
         h = L.rmsnorm(params["cross_norm"], x, th["cross_norm"],
@@ -113,7 +138,7 @@ def _apply_attn_block(cfg, params, x, th, positions, *, causal=True,
         y, aux = moe_fn(cfg, params["moe"], h, subth(th, "moe"))
     else:
         y = L.swiglu(params["mlp"], h, subth(th, "mlp"), f=cfg.d_ff)
-    return x + y, aux
+    return x + _branch(cfg, y), aux
 
 
 def _cross_attention(cfg, params, x, th, enc_out):
@@ -205,9 +230,11 @@ def _build_decoder(cfg: ModelConfig, rwkv_formulation: str) -> Model:
     pat = cfg.pattern()
     d, v = cfg.d_model, cfg.vocab_size
 
-    spec: dict = {"embed": {"w": P((v, d), init="embed", dtype=cfg.dtype)},
-                  "final_norm": L.rmsnorm_spec(d, dtype=cfg.dtype),
-                  "head": {"w": P((d, v), dtype=cfg.dtype)}}
+    spec: dict = {"embed": {"w": P((v, d), init="embed", dtype=cfg.dtype,
+                                   tied=cfg.tie_embeddings)},
+                  "final_norm": L.rmsnorm_spec(d, dtype=cfg.dtype)}
+    if not cfg.tie_embeddings:
+        spec["head"] = {"w": P((d, v), dtype=cfg.dtype)}
 
     kinds = sorted(set(pat))
     if cfg.shared_attention:
@@ -288,12 +315,6 @@ def _build_decoder(cfg: ModelConfig, rwkv_formulation: str) -> Model:
     layout = GroupLayout({"lora": lora_tree}) if lora_on else base_layout
 
     # ---------------- shared helpers ----------------
-
-    def embed(params, tokens, th):
-        return dpl.dp_embed(params["embed"]["w"], tokens, th["embed"])
-
-    def head(params, x, th):
-        return dpl.dp_linear(params["head"]["w"], None, x, th["head"])
 
     def positions_of(batch, bsz, t):
         if "positions" in batch:
@@ -422,7 +443,7 @@ def _build_decoder(cfg: ModelConfig, rwkv_formulation: str) -> Model:
             # base groups get +inf (frozen, unused grads DCE'd); real
             # thresholds arrive only for the lora/... groups
             th = {**base_layout.pack_value(jnp.inf, bsz), **th}
-        x = embed(params, tokens, th)
+        x = _embed(cfg, params, tokens, th)
         tv = 0
         if "vision_embeds" in batch:  # VLM: prepend stub patch embeddings
             ve = batch["vision_embeds"].astype(x.dtype)
@@ -450,7 +471,7 @@ def _build_decoder(cfg: ModelConfig, rwkv_formulation: str) -> Model:
             x = x[:, tv:]
         x = L.rmsnorm(params["final_norm"], x, th["final_norm"],
                       eps=cfg.norm_eps)
-        logits = head(params, x, th)  # (B, T, V)
+        logits = _lm_head(cfg, params, x, th, tokens)  # (B, T, V)
         targets = batch["targets"]  # (B, T) with -1 = ignore
         ce = _per_example_ce(logits, targets)
         if cfg.mtp_depth:
@@ -464,7 +485,7 @@ def _build_decoder(cfg: ModelConfig, rwkv_formulation: str) -> Model:
         tokens = batch["tokens"]
         bsz, t = tokens.shape
         nxt = jnp.concatenate([tokens[:, 1:], tokens[:, -1:]], axis=1)
-        e = embed(params, nxt, th)
+        e = _embed(cfg, params, nxt, th)
         h = L.linear(params["mtp"]["proj"],
                      jnp.concatenate([x, e], axis=-1),
                      th["mtp/proj"])
@@ -475,7 +496,7 @@ def _build_decoder(cfg: ModelConfig, rwkv_formulation: str) -> Model:
                                  moe_layer=False)
         h = L.rmsnorm(params["mtp"]["norm"], h, th["mtp/norm"],
                       eps=cfg.norm_eps)
-        logits = head(params, h, th)
+        logits = _lm_head(cfg, params, h, th, tokens)
         tgt = batch["targets"]
         tgt2 = jnp.concatenate(
             [tgt[:, 2:], jnp.full((bsz, 2), -1, tgt.dtype)], axis=1)
@@ -494,7 +515,7 @@ def _build_decoder(cfg: ModelConfig, rwkv_formulation: str) -> Model:
         th = base_layout.pack_value(jnp.inf, bsz)
         if lora_on:
             th = {**th, **layout.pack_value(jnp.inf, bsz)}
-        x = embed(params, tokens, th)
+        x = _embed(cfg, params, tokens, th)
         tv = 0
         if "vision_embeds" in batch:
             ve = batch["vision_embeds"].astype(x.dtype)
@@ -514,7 +535,7 @@ def _build_decoder(cfg: ModelConfig, rwkv_formulation: str) -> Model:
         x = x[:, -1:]
         x = L.rmsnorm(params["final_norm"], x, th["final_norm"],
                       eps=cfg.norm_eps)
-        logits = head(params, x, th)
+        logits = _lm_head(cfg, params, x, th, tokens[:, -1:])
         return logits[:, 0]
 
     m = Model(cfg=cfg, spec=spec, layout=layout, loss_fn=loss_fn,
@@ -787,7 +808,7 @@ def _make_decoder_serve(cfg: ModelConfig, spec, layout):
         b = token.shape[0]
         pos = cache["pos"]
         th = layout.pack_value(jnp.inf, b)
-        x = dpl.dp_embed(params["embed"]["w"], token, th["embed"])
+        x = _embed(cfg, params, token, th)
         new_cache = dict(cache)
 
         if cfg.shared_attention:
@@ -919,7 +940,7 @@ def _make_decoder_serve(cfg: ModelConfig, spec, layout):
                             cfg, bp["attn"], hn, mk("attn"), latpool,
                             cache["pt"], pos, active=active, lora=lp,
                             tenant=tenant)
-                        h = h + att
+                        h = h + _branch(cfg, att)
                         hn = L.rmsnorm(bp["mlp_norm"], h, inf_b,
                                        eps=cfg.norm_eps)
                         if moe_layer:
@@ -930,7 +951,7 @@ def _make_decoder_serve(cfg: ModelConfig, spec, layout):
                         else:
                             y = L.swiglu(bp["mlp"], hn, mk("mlp"),
                                          f=cfg.d_ff)
-                        return h + y, lat_n
+                        return h + _branch(cfg, y), lat_n
 
                     x, lat_n = jax.lax.scan(
                         body, x, (params[name], ls,
@@ -944,7 +965,7 @@ def _make_decoder_serve(cfg: ModelConfig, spec, layout):
                         att, ckv_n, krope_n = A.mla_decode(
                             cfg, bp["attn"], hn, mk("attn"), ckv, krope, pos,
                             active=active, lora=lp, tenant=tenant)
-                        h = h + att
+                        h = h + _branch(cfg, att)
                         hn = L.rmsnorm(bp["mlp_norm"], h, inf_b,
                                        eps=cfg.norm_eps)
                         if moe_layer:
@@ -955,7 +976,7 @@ def _make_decoder_serve(cfg: ModelConfig, spec, layout):
                         else:
                             y = L.swiglu(bp["mlp"], hn, mk("mlp"),
                                          f=cfg.d_ff)
-                        return h + y, (ckv_n, krope_n)
+                        return h + _branch(cfg, y), (ckv_n, krope_n)
 
                     x, (ckv_n, kr_n) = jax.lax.scan(
                         body, x, (params[name], ls, cache[f"{name}_ckv"],
@@ -971,7 +992,7 @@ def _make_decoder_serve(cfg: ModelConfig, spec, layout):
                             cfg, bp["attn"], hn, mk("attn"), kpool, vpool,
                             cache["pt"], pos, active=active, lora=lp,
                             tenant=tenant)
-                        h = h + att
+                        h = h + _branch(cfg, att)
                         hn = L.rmsnorm(bp["mlp_norm"], h, inf_b,
                                        eps=cfg.norm_eps)
                         if moe_layer:
@@ -982,7 +1003,7 @@ def _make_decoder_serve(cfg: ModelConfig, spec, layout):
                         else:
                             y = L.swiglu(bp["mlp"], hn, mk("mlp"),
                                          f=cfg.d_ff)
-                        return h + y, (kp_n, vp_n)
+                        return h + _branch(cfg, y), (kp_n, vp_n)
 
                     x, (kp_n, vp_n) = jax.lax.scan(
                         body, x, (params[name], ls, cache[f"{name}_kpool"],
@@ -998,7 +1019,7 @@ def _make_decoder_serve(cfg: ModelConfig, spec, layout):
                             cfg, bp["attn"], hn, mk("attn"), ck, cv, pos,
                             window=window, active=active, lora=lp,
                             tenant=tenant)
-                        h = h + att
+                        h = h + _branch(cfg, att)
                         hn = L.rmsnorm(bp["mlp_norm"], h, inf_b,
                                        eps=cfg.norm_eps)
                         if moe_layer:
@@ -1009,7 +1030,7 @@ def _make_decoder_serve(cfg: ModelConfig, spec, layout):
                         else:
                             y = L.swiglu(bp["mlp"], hn, mk("mlp"),
                                          f=cfg.d_ff)
-                        return h + y, (ck_n, cv_n)
+                        return h + _branch(cfg, y), (ck_n, cv_n)
 
                     x, (ck_n, cv_n) = jax.lax.scan(
                         body, x, (params[name], ls, cache[f"{name}_k"],
@@ -1019,7 +1040,7 @@ def _make_decoder_serve(cfg: ModelConfig, spec, layout):
 
         x = L.rmsnorm(params["final_norm"], x, th["final_norm"],
                       eps=cfg.norm_eps)
-        logits = dpl.dp_linear(params["head"]["w"], None, x, th["head"])
+        logits = _lm_head(cfg, params, x, th, token)
         new_cache["pos"] = (pos + 1 if active is None
                             else pos + active.astype(jnp.int32))
         return logits[:, 0], new_cache

@@ -49,7 +49,10 @@ def test_forward_shapes_and_finite(arch, built):
 def test_one_dp_train_step(arch, built):
     cfg, m, params = built(arch)
     batch = concrete_train_batch(cfg, 4, T, jax.random.PRNGKey(2))
-    dpc = DPConfig(mode="per_layer", sigma=0.8, sampling_rate=0.1, steps=10,
+    # a tied embedding / LM head clips exactly only through BK's flat or
+    # group modes (clipping.check_tied_mode)
+    mode = "ghost_flat" if cfg.tie_embeddings else "per_layer"
+    dpc = DPConfig(mode=mode, sigma=0.8, sampling_rate=0.1, steps=10,
                    adaptive=True, init_threshold=1.0)
     init_fn, step_fn, plan = make_dp_train_step(
         m.loss_fn, getattr(m, "dp_spec", m.spec), m.layout,

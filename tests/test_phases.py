@@ -106,6 +106,37 @@ def test_the_norm_and_clipped_sum_kernels_are_in_the_backward(
     assert hlo.container_ops(per_layer_text)  # the layer loops
 
 
+_AFTER_BACKWARD = re.compile(
+    r'op_name="[^"]*\b(bk_epilogue_contract|dp_tied_cross)\b')
+
+
+def test_the_bk_epilogue_and_the_tied_cross_term_are_in_the_backward():
+    """A ghost_flat BK step of the tied model: the epilogue's clipped sums
+    and the cross term run after the transposed pass, not transposed
+    themselves, and count as backward like per_layer's clipped sums."""
+    cfg = get_config("minicpm-2b", reduced=True)
+    m = build_model(cfg)
+    dpc = DPConfig(mode="ghost_flat", sigma=1.0, sampling_rate=0.1,
+                   steps=10, backend="xla", autotune=False)
+    init_fn, step_fn, _ = make_dp_train_step(
+        m.loss_fn, m.spec, m.layout, optim.adam(1e-3), dpc, batch_size=B)
+    params = abstract_params(m.spec)
+    opt_abs, dp_abs = jax.eval_shape(init_fn, params)
+    batch = {k: jax.ShapeDtypeStruct((B, T), jnp.int32)
+             for k in ("tokens", "targets")}
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32)
+    text = jax.jit(step_fn).lower(params, opt_abs, dp_abs, batch,
+                                  key).compile().as_text()
+    phases = hlo.op_phases(text)
+    after = {ins.name: ins.rest for instrs in hlo.parse_module(text).values()
+             for ins in instrs if _AFTER_BACKWARD.search(ins.rest)
+             and ins.op not in hlo.CONTAINER_OPS}
+    scopes = {_AFTER_BACKWARD.search(r).group(1) for r in after.values()}
+    assert scopes == {"bk_epilogue_contract", "dp_tied_cross"}
+    assert {phases[k] for k in after} == {hlo.BACKWARD}
+    assert phase_problems(text) == []
+
+
 def test_the_scopes_add_no_op(monkeypatch):
     def op_counts(text):
         return collections.Counter(ins.op for instrs in hlo.parse_module(

@@ -231,3 +231,60 @@ def test_cell_step_clip_reduce_takes_bf16_unpadded(one_chip):
         shapes.append((int(din), int(dout)))
     assert sorted(shapes) == sorted([(D, 48 * HD), (32 * HD, D),
                                      (D, 2 * FF), (FF, D), (D, VOCAB)])
+
+
+def test_minicpm_cell_step_compiles_for_v5e_and_fits(one_chip):
+    """The `train.minicpm-2b.ghost_flat.t2048` cell's whole step for a
+    described v5e: MiniCPM-2B as published cut to 6 of 40 layers, tied and
+    muP, ghost_flat through BK, B=2, T=2048, the Pallas kernels as `auto`
+    picks them on the chip. The tied head's clipped sum is one
+    `bk_scale_contract` into the table's (V, d) layout, the cross term is
+    in the program, and the compiler's memory estimate fits one chip's
+    16 GB, checked here before the step is run on a chip."""
+    import dataclasses
+    import re
+
+    from repro import optim
+    from repro.configs import get_config
+    from repro.core.dp_sgd import DPConfig, make_dp_train_step
+    from repro.core.spec import abstract_params
+    from repro.kernels import backend as KB
+    from repro.models.transformer import build_model
+
+    b, t = 2, 2048
+    cfg = dataclasses.replace(get_config("minicpm-2b"), num_layers=6)
+    m = build_model(cfg)
+    dpc = DPConfig(mode="ghost_flat", execution="bk", sigma=1.0,
+                   sampling_rate=b / 1024, steps=1000, adaptive=False,
+                   backend="pallas", autotune=False)
+    init_fn, step_fn, _ = make_dp_train_step(
+        m.loss_fn, m.spec, m.layout, optim.adam(1e-3), dpc, batch_size=b)
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(on_chip, abstract_params(m.spec))
+    opt_abs, dp_abs = jax.tree_util.tree_map(
+        on_chip, jax.eval_shape(init_fn, params))
+    batch = {k: on_chip(jax.ShapeDtypeStruct((b, t), jnp.int32))
+             for k in ("tokens", "targets")}
+    key = on_chip(jax.ShapeDtypeStruct((2,), jnp.uint32))
+    with KB.scoped("pallas", interpret=False):
+        compiled = jax.jit(step_fn, donate_argnums=(0, 1, 2)).lower(
+            params, opt_abs, dp_abs, batch, key).compile()
+    text = compiled.as_text()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    print(f"minicpm-2b-6l ghost_flat BK step, v5e memory_analysis: "
+          f"arguments {mem.argument_size_in_bytes} B, outputs "
+          f"{mem.output_size_in_bytes} B, aliased {mem.alias_size_in_bytes}"
+          f" B, temporaries {mem.temp_size_in_bytes} B, total {total} B")
+    assert total < 16e9
+    # (1, V, d) out, V = 122,753 rows padded up to the kernel's row tile
+    heads = [int(mt.group(1)) for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln
+             and "bk_scale_contract" in ln
+             for mt in [re.search(r"= f32\[1,(\d+),2304\]", ln)] if mt]
+    assert heads and all(v >= 122753 for v in heads), heads
+    assert "dp_tied_cross" in text
