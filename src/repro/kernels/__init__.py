@@ -2,10 +2,10 @@
 plus the backend engine that makes them load-bearing.
 
   ghost_norm.py    per-example grad norms² (full + per-shard blocked)
-  clip_reduce.py   fused clip-scale-accumulate Σ_i c_i A_iᵀ G_i
+  clip_reduce.py   fused clip-scale-accumulate Σ_i c_i A_iᵀ G_i, and the
+                   book-keeping epilogue's per stack slice
+                   (`scale_contract`, over residuals cached by core.bk)
   fused_clip.py    norms² + clip + reduce in ONE pass over A, G
-  bk.py            book-keeping epilogue Σ_i f_i A_iᵀ G_i per stack slice
-                   (the contraction over residuals cached by core.bk)
   ref.py           pure-jnp oracles (the allclose ground truth)
   ops.py           thin jitted wrappers for tests/benchmarks
   backend.py       xla | pallas | auto engine registry + scoped config

@@ -9,10 +9,11 @@ backends are registered:
           gram_chunked / outer auto-dispatch). Always available; the
           semantics oracle for the others.
   pallas  real `pallas_call` kernels for the linear-layer hot paths
-          (kernels/ghost_norm.py, kernels/clip_reduce.py,
-          kernels/fused_clip.py). On TPU they compile to Mosaic; on CPU
-          they run in interpret mode (correctness validation — slow, tests
-          only). Ops with no kernel fall back to the xla implementations.
+          (kernels/ghost_norm.py; kernels/clip_reduce.py, which also
+          serves BK's scale_contract; kernels/fused_clip.py). On TPU they
+          compile to Mosaic; on CPU they run in interpret mode
+          (correctness validation — slow, tests only). Ops with no
+          kernel fall back to the xla implementations.
   auto    per-op empirical choice between the two: when an autotune table
           (repro.kernels.autotune) is installed and has measured this
           (op, shape-bucket), the measured argmin wins — on ANY jax
@@ -71,8 +72,8 @@ import jax.numpy as jnp
 from repro.core import ghost
 from repro.core.ghost import clip_factor
 from repro.kernels import autotune
-from repro.kernels.bk import scale_contract as scale_contract_kernel
 from repro.kernels.clip_reduce import clip_reduce
+from repro.kernels.clip_reduce import scale_contract as scale_contract_kernel
 from repro.kernels.fused_clip import fused_norm_clip
 from repro.kernels.fused_clip import padded_dims as fused_clip_padded_dims
 from repro.kernels.ghost_norm import ghost_norm, ghost_norm_blocked
@@ -106,11 +107,10 @@ class EngineConfig:
       Defaults suit ~16 MB VMEM cores; the autotune sweep measures
       alternatives.
     * `bi`, `bj`: the din and dout tiles of `clip_reduce` and
-      `scale_contract`. `None` (the default) means derived:
-      `clip_reduce.tiles` sizes `clip_reduce`'s from the shape and dtype,
-      and `scale_contract` keeps its own 256. An explicit value overrides
-      both. `clip_reduce`'s row tile is always derived, since it has to
-      divide the (padded) sequence.
+      `scale_contract`, which share one kernel. `None` (the default)
+      means derived: `clip_reduce.tiles` sizes both from the shape and
+      dtype. An explicit value overrides both. Their row tile is always
+      derived, since it has to divide the (padded) sequence.
     * `interpret` (default `None` = interpret off-TPU, compiled on
       TPU): force pallas interpret mode either way.
     * `vmem_limit_bytes` (default 12 MiB): kernel-selection guard —
@@ -342,7 +342,6 @@ class PallasBackend(Backend):
 
     def scale_contract(self, a, g, factors):
         return scale_contract_kernel(a, g, factors, **self._feature_tiles(),
-                                     bt=self.config.bt,
                                      interpret=self._interpret())
 
     def paged_impl(self, *, t=None, din=None, dout=None) -> str:

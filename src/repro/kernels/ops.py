@@ -18,8 +18,7 @@ from functools import partial
 
 import jax
 
-from repro.kernels.bk import scale_contract
-from repro.kernels.clip_reduce import clip_reduce
+from repro.kernels.clip_reduce import clip_reduce, scale_contract
 from repro.kernels.fused_clip import fused_norm_clip
 from repro.kernels.ghost_norm import ghost_norm, ghost_norm_blocked
 from repro.kernels.paged_attn import paged_attn
@@ -58,8 +57,8 @@ def fused_norm_clip_op(a, g, c, extra_norms_sq=None, *, bt: int = 256):
 
 
 @partial(jax.jit, static_argnames=("bi", "bj", "bt"))
-def scale_contract_op(a, g, factors, *, bi: int = 256, bj: int = 256,
-                      bt: int = 256):
+def scale_contract_op(a, g, factors, *, bi: int | None = None,
+                      bj: int | None = None, bt: int | None = None):
     return scale_contract(a, g, factors, bi=bi, bj=bj, bt=bt,
                           interpret=_interpret())
 

@@ -1,6 +1,6 @@
 """Book-keeping (BK) engine: single-backprop flat/group clipping.
 
-Contract under test (repro.core.bk + kernels/bk.py):
+Contract under test (repro.core.bk + kernels/clip_reduce.scale_contract):
   * bk ≡ twopass — clipped grads AND per-group norms² identical for
     ghost_flat and per_group, including microbatch accumulation and the
     DP-LoRA trainable_key path;
@@ -170,20 +170,64 @@ def test_bk_falls_back_on_shared_site_params():
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("shape", [(2, 4, 300, 65, 130), (1, 3, 17, 8, 5),
-                                   (3, 2, 256, 130, 64)])
-def test_scale_contract_kernel_matches_ref(shape):
-    from repro.kernels.bk import scale_contract
+_T128 = dict(bi=128, bj=128, bt=16)
+
+
+# Past the first three (f32, derived tiles, uniform factors): bf16 operands
+# against the f32 oracle with explicit 128 tiles and the derived ones; din
+# 300 and 2700 (ragged last blocks, as the tied head's V = 122,753 has),
+# dout 200; T = 40 and 20, no multiple of the row tile (16 explicit, 48 and
+# 32 derived); S up to 3; factors spanning 0 to 1, the first example at 0.
+@pytest.mark.parametrize("shape,dtype,tiles,span", [
+    pytest.param((2, 4, 300, 65, 130), jnp.float32, {}, False, id="shape0"),
+    pytest.param((1, 3, 17, 8, 5), jnp.float32, {}, False, id="shape1"),
+    pytest.param((3, 2, 256, 130, 64), jnp.float32, {}, False, id="shape2"),
+    pytest.param((3, 2, 40, 300, 200), jnp.bfloat16, _T128, True,
+                 id="bf16-ragged-t128"),
+    pytest.param((3, 2, 40, 300, 200), jnp.bfloat16, {}, True,
+                 id="bf16-ragged-derived"),
+    pytest.param((2, 2, 20, 2700, 130), jnp.bfloat16, {}, True,
+                 id="bf16-wide-derived"),
+    pytest.param((2, 3, 40, 300, 200), jnp.float32, _T128, True,
+                 id="f32-ragged-t128"),
+])
+def test_scale_contract_kernel_matches_ref(shape, dtype, tiles, span):
+    from repro.kernels.clip_reduce import scale_contract
     from repro.kernels.ref import scale_contract_ref
     s, b, t, di, do = shape
     k = jax.random.PRNGKey(0)
-    a = jax.random.normal(jax.random.fold_in(k, 1), (s, b, t, di))
-    g = jax.random.normal(jax.random.fold_in(k, 2), (s, b, t, do))
+    a = jax.random.normal(jax.random.fold_in(k, 1), (s, b, t, di)
+                          ).astype(dtype)
+    g = jax.random.normal(jax.random.fold_in(k, 2), (s, b, t, do)
+                          ).astype(dtype)
     f = jax.random.uniform(jax.random.fold_in(k, 3), (s, b))
-    got = scale_contract(a, g, f, interpret=True)
+    if span:
+        f = jnp.linspace(0.0, 1.0, s * b).reshape(s, b)
+    got = scale_contract(a, g, f, **tiles, interpret=True)
     np.testing.assert_allclose(np.asarray(got),
                                np.asarray(scale_contract_ref(a, g, f)),
                                rtol=2e-5, atol=1e-4)
+
+
+@pytest.mark.parametrize("bt", [None, 16])
+def test_scale_contract_sensitivity_bound(bt):
+    """With f[s, b] = C / ‖A[s,b]ᵀG[s,b]‖, each example's clipped sum from
+    bf16 operands has Frobenius norm at most C·(1 + 1e-5) in every stack
+    slice: the factor stays an unquantized f32 that scales the f32
+    partial sums."""
+    from repro.kernels.clip_reduce import scale_contract
+    from repro.kernels.ref import ghost_norm_ref
+    s, b, t, din, dout, clip = 2, 3, 48, 200, 300, 0.7
+    k = jax.random.PRNGKey(11)
+    a = jax.random.normal(k, (s, b, t, din)).astype(jnp.bfloat16)
+    g = jax.random.normal(jax.random.fold_in(k, 1), (s, b, t, dout)
+                          ).astype(jnp.bfloat16)
+    f = clip / jnp.sqrt(jax.vmap(ghost_norm_ref)(a, g))
+    for i in range(b):
+        got = scale_contract(a[:, i:i + 1], g[:, i:i + 1], f[:, i:i + 1],
+                             bt=bt, interpret=True)
+        norms = jnp.linalg.norm(got.reshape(s, -1), axis=-1)
+        assert float(jnp.max(norms)) <= clip * (1 + 1e-5), (i, norms)
 
 
 def test_scale_contract_backend_op_parity():

@@ -9,8 +9,7 @@ except ImportError:  # container lacks hypothesis; deterministic shim
     from _hypothesis_compat import given, settings, st
 
 from repro.kernels import ref
-from repro.kernels.bk import scale_contract
-from repro.kernels.clip_reduce import clip_reduce
+from repro.kernels.clip_reduce import clip_reduce, scale_contract
 from repro.kernels.fused_clip import fused_norm_clip
 from repro.kernels.ghost_norm import ghost_norm, ghost_norm_blocked
 from repro.kernels.paged_attn import paged_attn

@@ -1,5 +1,7 @@
 """The Pallas kernels of the main path compile for a TPU v5e at qwen3-4b
-widths (d_model 2560, d_ff 9728, 8 KV heads of 128, B=4, T=512).
+widths (d_model 2560, d_ff 9728, 8 KV heads of 128, B=4, T=512), and BK's
+epilogue at MiniCPM-2B's (d_model 2304, d_ff 5760, vocab 122,753, B=2,
+T=2048).
 
 The chip is described, not attached: the TPU compiler refuses here what the
 chip would refuse (misaligned blocks, too much VMEM), and no test needs a
@@ -13,8 +15,7 @@ import jax.numpy as jnp
 import pytest
 from jax.extend import core as jex_core
 
-from repro.kernels.bk import scale_contract
-from repro.kernels.clip_reduce import clip_reduce
+from repro.kernels.clip_reduce import clip_reduce, scale_contract
 from repro.kernels.fused_clip import fused_norm_clip
 from repro.kernels.ghost_norm import ghost_norm, ghost_norm_blocked
 from repro.kernels.paged_attn import paged_attn
@@ -22,6 +23,7 @@ from repro.kernels.paged_attn import paged_attn
 B, T, D, FF, VOCAB = 4, 512, 2560, 9728, 151936
 KV, G, HD, PAGE = 8, 4, 128, 16
 PAGES = 4 * 43 + 1  # 4 slots x 688-token horizon, plus the trash page
+MC_D, MC_FF, MC_VOCAB = 2304, 5760, 122753  # MiniCPM-2B
 
 
 @pytest.fixture(scope="module")
@@ -81,6 +83,17 @@ CASES = {
     "bk_scale_contract": (lambda a, g, f: scale_contract(a, g, f),
                           [_bf16((4, B, T, D)), _bf16((4, B, T, FF)),
                            _f32((4, B))]),
+    # derived tiles at MiniCPM-2B widths, B=2, T=2048: the tied head into
+    # the table's (V, d) layout (48 blocks of 2560 over V = 122,753, a
+    # ragged last one, about 83 MiB of VMEM) and the stacked gate+up of six
+    # layers (2304 -> 11520, 5 blocks of 2304)
+    "bk_scale_contract.head": (lambda a, g, f: scale_contract(a, g, f),
+                               [_bf16((2, 2048, MC_VOCAB)),
+                                _bf16((2, 2048, MC_D)), _f32((2,))]),
+    "bk_scale_contract.gate_up": (lambda a, g, f: scale_contract(a, g, f),
+                                  [_bf16((6, 2, 2048, MC_D)),
+                                   _bf16((6, 2, 2048, 2 * MC_FF)),
+                                   _f32((6, 2))]),
     "paged_attn": (lambda q, k, v, pt, pos: paged_attn(
         q, k, v, pt, pos, scale=HD ** -0.5),
         [_bf16((4, KV, G, HD)), _bf16((PAGES, PAGE, KV, HD)),
@@ -281,10 +294,80 @@ def test_minicpm_cell_step_compiles_for_v5e_and_fits(one_chip):
           f"{mem.output_size_in_bytes} B, aliased {mem.alias_size_in_bytes}"
           f" B, temporaries {mem.temp_size_in_bytes} B, total {total} B")
     assert total < 16e9
-    # (1, V, d) out, V = 122,753 rows padded up to the kernel's row tile
+    # (1, V, d) out, V = 122,753 rows
     heads = [int(mt.group(1)) for ln in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in ln
              and "bk_scale_contract" in ln
              for mt in [re.search(r"= f32\[1,(\d+),2304\]", ln)] if mt]
     assert heads and all(v >= 122753 for v in heads), heads
     assert "dp_tied_cross" in text
+
+
+def test_minicpm_step_scale_contract_takes_bf16_unpadded(one_chip):
+    """The MiniCPM cell's step (two of its layers, so each layer weight is
+    a stack of S = 2 and the tied head a stack of one) for a described v5e:
+    each `bk_scale_contract` dot takes bf16 blocks, the tied head's
+    included, and each call takes the cached residuals as they are, (S,
+    B·T, din) and (S, B·T, dout), and returns the (S, din, dout) f32 sum
+    itself, so no pad of the residuals and no slice of the sum surround
+    it."""
+    import dataclasses
+    import re
+
+    from repro import optim
+    from repro.configs import get_config
+    from repro.core.dp_sgd import DPConfig, make_dp_train_step
+    from repro.core.spec import abstract_params
+    from repro.kernels import backend as KB
+    from repro.models.transformer import build_model
+
+    b, t, s = 2, 2048, 2
+    cfg = dataclasses.replace(get_config("minicpm-2b"), num_layers=s)
+    m = build_model(cfg)
+    dpc = DPConfig(mode="ghost_flat", execution="bk", sigma=1.0,
+                   sampling_rate=b / 1024, steps=1000, adaptive=False,
+                   backend="pallas", autotune=False)
+    init_fn, step_fn, _ = make_dp_train_step(
+        m.loss_fn, m.spec, m.layout, optim.adam(1e-3), dpc, batch_size=b)
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(on_chip, abstract_params(m.spec))
+    opt_abs, dp_abs = jax.tree_util.tree_map(
+        on_chip, jax.eval_shape(init_fn, params))
+    batch = {k: on_chip(jax.ShapeDtypeStruct((b, t), jnp.int32))
+             for k in ("tokens", "targets")}
+    key = on_chip(jax.ShapeDtypeStruct((2,), jnp.uint32))
+    with KB.scoped("pallas", interpret=False):
+        traced = jax.jit(step_fn).trace(params, opt_abs, dp_abs, batch, key)
+        text = traced.lower().compile().as_text()
+    dots = [[str(v.aval.dtype) for v in dot.invars]
+            for k in _eqns(traced.jaxpr.jaxpr)
+            if k.primitive.name == "pallas_call"
+            and str(k.params["name"]) == "bk_scale_contract"
+            for dot in _eqns(k.params["jaxpr"])
+            if dot.primitive.name == "dot_general"]
+    assert len(dots) == 5 and all(d == ["bfloat16", "bfloat16"]
+                                  for d in dots), dots
+    call = re.compile(
+        r"= f32\[(\d+),(\d+),(\d+)\]\S* custom-call\(.*"
+        r"operand_layout_constraints=\{f32\[\d+,2\]\{1,0\}, "
+        r"(\w+)\[(\d+),(\d+),(\d+)\]\{2,1,0\}, "
+        r"(\w+)\[(\d+),(\d+),(\d+)\]\{2,1,0\}\}")
+    shapes = []
+    for ln in text.splitlines():
+        if ('custom_call_target="tpu_custom_call"' not in ln
+                or not re.search(r"bk_scale_contract\)?/pallas_call", ln)):
+            continue
+        mt = call.search(ln)
+        assert mt, ln[:300]
+        ss, din, dout, ta, sa, ra, ca, tg, sg, rg, cg = mt.groups()
+        assert (ta, tg) == ("bf16", "bf16"), ln[:300]
+        assert (sa, ra, ca) == (ss, str(b * t), din), ln[:300]
+        assert (sg, rg, cg) == (ss, str(b * t), dout), ln[:300]
+        shapes.append((int(ss), int(din), int(dout)))
+    d, ff = cfg.d_model, cfg.d_ff
+    assert sorted(shapes) == sorted([
+        (1, cfg.vocab_size, d), (s, d, 3 * d), (s, d, d), (s, d, 2 * ff),
+        (s, ff, d)]), shapes
