@@ -2,7 +2,7 @@
 
 Paper: under the SAME number of training epochs, adaptive per-layer
 clipping matches flat clipping's utility — which, combined with the per-
-update speed advantage (bench_throughput), yields the wall-time win.
+update speed advantage, yields the wall-time win.
 Testbed: tiny LM on the synthetic Markov corpus, loss after E epochs.
 """
 from __future__ import annotations

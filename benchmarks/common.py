@@ -1,27 +1,19 @@
-"""Shared benchmark harness utilities.
-
-JAX is imported inside the functions that use it: suites whose parent only
-starts worker processes (`bench_startup`, `bench_sharded`) import this module
-without touching JAX, so the workers are the only processes on the device.
-"""
+"""Shared helpers of the paper-utility suites: CSV rows, a timer, and the
+small DP MLP classifier they train."""
 from __future__ import annotations
 
 import time
 
+import jax
+import jax.numpy as jnp
+import numpy as np
 
-def topology() -> dict:
-    """Device-topology metadata stamped into every BENCH_*.json record, so
-    numbers from different machines / virtual-device configurations are
-    never compared blind across PRs. The same stamp keys the on-disk
-    autotune table and compile cache (repro.kernels.autotune)."""
-    from repro.kernels.autotune import topology_stamp
-    return topology_stamp()
+from repro.core import dp_layers as dpl
+from repro.core.spec import GroupLayout, P
 
 
 def timeit(fn, *args, warmup: int = 2, iters: int = 5) -> float:
     """Median wall time per call in microseconds (blocks on results)."""
-    import jax
-    import numpy as np
     for _ in range(warmup):
         jax.block_until_ready(fn(*args))
     ts = []
@@ -48,11 +40,6 @@ def mlp_classifier(dim: int, width: int, depth: int, classes: int,
     feature_scales: optional per-layer input magnification — creates the
     strongly NON-uniform per-layer gradient norms of the paper's Figure 2
     (what makes hand-set uniform per-layer thresholds hurt)."""
-    import jax
-    import jax.numpy as jnp
-
-    from repro.core import dp_layers as dpl
-    from repro.core.spec import GroupLayout, P
     spec = {}
     sizes = [dim] + [width] * depth + [classes]
     for i, (a, b) in enumerate(zip(sizes[:-1], sizes[1:])):

@@ -9,8 +9,7 @@ Two cooperating passes over the compiled artifacts of
     draw (with a leaf-unique key lineage) reaches it.
   * `rules` — named, severity-tagged rules over the post-SPMD HLO text
     (collective leaks across the model axis, backward-pass counts,
-    donation coverage, shape stability), built on the `hlo` parser that
-    previously lived at `repro.launch.hlo_analysis`.
+    donation coverage, shape stability), built on the `hlo` parser.
 
 `repro.launch.audit` drives both over the clipping x execution x mesh
 matrix and emits benchmarks/AUDIT.json.

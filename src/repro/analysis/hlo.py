@@ -604,8 +604,8 @@ def summarize_axis_rows(rows: list[dict]) -> dict:
     """Aggregate `classify_collectives` rows to {axes-key: {count, bytes}}.
 
     Keys are '+'-joined spanned axes ('model', 'data', 'data+model', ...)
-    or 'intra' for degenerate single-device groups — the shape consumed by
-    BENCH_sharded.json and the zero-model-norm-traffic assertions.
+    or 'intra' for degenerate single-device groups — the shape the dry-run
+    records under `collectives_by_axis`.
     """
     out: dict[str, dict] = {}
     for r in rows:

@@ -51,28 +51,8 @@ def outer_path_cost(t: int, din: int, dout: int) -> int:
 # T² work — a large win at long sequence.
 DEFAULT_OUTER_MAX_ELEMS = 1 << 22  # per-example materialized grad cap
 DEFAULT_GRAM_CHUNK = 1024  # row-block size for the chunked gram path
-_OUTER_MAX_ELEMS = DEFAULT_OUTER_MAX_ELEMS
-_GRAM_CHUNK = DEFAULT_GRAM_CHUNK
 
 _EPS = 1e-12
-
-
-def configure(*, outer_max_elems: int | None = None,
-              gram_chunk: int | None = None) -> dict:
-    """Set module-global ghost-path policy (returns the previous values).
-
-    DEPRECATED for engine users: prefer `repro.kernels.backend.scoped(...)`,
-    which threads the policy through without mutating globals — jitted step
-    functions then capture their policy statically at trace time. Direct
-    callers of this module still honor these globals as defaults.
-    """
-    global _OUTER_MAX_ELEMS, _GRAM_CHUNK
-    prev = {"outer_max_elems": _OUTER_MAX_ELEMS, "gram_chunk": _GRAM_CHUNK}
-    if outer_max_elems is not None:
-        _OUTER_MAX_ELEMS = outer_max_elems
-    if gram_chunk is not None:
-        _GRAM_CHUNK = gram_chunk
-    return prev
 
 
 def clip_factor(c: jax.Array, norms_sq: jax.Array) -> jax.Array:
@@ -103,7 +83,8 @@ def linear_norms_sq(a: jax.Array, g: jax.Array, *,
     a: (B, T, d_in) or (B, d_in) activations into the layer.
     g: (B, T, d_out) or (B, d_out) cotangents w.r.t. the layer output.
     force_path: 'gram' | 'gram_chunked' | 'outer' | None (auto).
-    outer_max_elems / gram_chunk: explicit policy (None -> module globals).
+    outer_max_elems / gram_chunk: explicit policy (None -> the DEFAULT_*
+    constants).
 
     Auto selection minimizes flops subject to a memory cap: the outer path
     transiently materializes (B, d_in, d_out) so it is only allowed for
@@ -111,9 +92,9 @@ def linear_norms_sq(a: jax.Array, g: jax.Array, *,
     (B, chunk, T) row blocks when T is large — the same blocking the Pallas
     kernel uses in VMEM.
     """
-    outer_cap = (_OUTER_MAX_ELEMS if outer_max_elems is None
+    outer_cap = (DEFAULT_OUTER_MAX_ELEMS if outer_max_elems is None
                  else outer_max_elems)
-    chunk = _GRAM_CHUNK if gram_chunk is None else gram_chunk
+    chunk = DEFAULT_GRAM_CHUNK if gram_chunk is None else gram_chunk
     a3, g3 = _as3d(a).astype(ACC_DTYPE), _as3d(g).astype(ACC_DTYPE)
     b, t, din = a3.shape
     dout = g3.shape[-1]
@@ -174,7 +155,7 @@ def embed_norms_sq(ids: jax.Array, g: jax.Array, *,
         ||grad_i||^2 = sum_{t,t'} 1[ids_t == ids_t'] <g_t, g_t'>
                      = < EqualityMask_i , G_i G_i^T >.
     """
-    chunk = _GRAM_CHUNK if gram_chunk is None else gram_chunk
+    chunk = DEFAULT_GRAM_CHUNK if gram_chunk is None else gram_chunk
     ids2 = ids.reshape(ids.shape[0], -1)
     g3 = _as3d(g).astype(ACC_DTYPE)
     b, t, d = g3.shape

@@ -7,8 +7,9 @@ plus the backend engine that makes them load-bearing.
                    (`scale_contract`, over residuals cached by core.bk)
   fused_clip.py    norms² + clip + reduce in ONE pass over A, G
   ref.py           pure-jnp oracles (the allclose ground truth)
-  ops.py           thin jitted wrappers for tests/benchmarks
+  ops.py           thin jitted wrappers that hit one kernel directly
   backend.py       xla | pallas | auto engine registry + scoped config
+  autotune.py      the sweep's measured (op, shape-bucket) table behind auto
 
 `repro.core.dp_layers` resolves every ghost op through `backend.active()`;
 import `backend` and use `backend.scoped("pallas")` (or

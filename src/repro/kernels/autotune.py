@@ -1,35 +1,24 @@
 """Measured kernel autotuning: an empirical table behind the `auto` backend.
 
-The static flop model in `repro.kernels.backend.choose_linear_path` predicts
-which implementation (xla reference path vs pallas kernel) wins for a given
-ghost-op shape — and `benchmarks/BENCH_kernels.json` already contradicts it
-on several shapes (e.g. pallas `clip_sum` measured faster than xla on CPU
-while the model resolves to xla off-TPU unconditionally). This module makes
-the `auto` decision *empirical*:
+The `auto` backend picks, per ghost op, between the xla reference path and
+the pallas kernel. Two sources decide that choice:
 
-  * a one-time per-(op, shape-bucket, backend) timing sweep (`sweep()` /
-    ``python -m repro.kernels.autotune --sweep``) measures the registered
-    backends on representative data and records the median wall time;
-  * results persist to a versioned on-disk JSON table keyed by the
-    **topology stamp** (jax backend, device kind, device count, XLA flags,
-    jax version) with a crc32 over the canonical payload — a table written
-    on a different topology, a different schema version, or a torn/corrupt
-    file loads as an EMPTY table (clean miss, never a crash) and is simply
-    rebuilt by the next sweep;
-  * `repro.kernels.backend.choose_op` consults the *installed* table at
-    trace time: the measured argmin wins on ANY jax backend (including the
-    interpret-mode kernels off-TPU — if they measured faster, they are
-    faster), and the static flop model remains the fallback for unmeasured
-    buckets;
-  * `benchmarks/bench_kernels.py` seeds measured entries from its sweep and
-    `benchmarks/roofline.py` seeds model-estimated entries for unmeasured
-    buckets, so a fleet image can ship a pre-warmed table and thousands of
-    workers never re-autotune.
+  * the timing sweep (`sweep()` / ``python -m repro.kernels.autotune
+    --sweep``) measures the registered backends per (op, shape-bucket) on
+    representative data and records the median wall time; every row of
+    the table is such a measurement, and its argmin wins on ANY jax
+    backend (including the interpret-mode kernels off-TPU — if they
+    measured faster, they are faster);
+  * the static flop model `repro.kernels.backend.choose_linear_path`
+    decides every bucket the sweep has not measured.
 
-Shapes are bucketed to the next power of two per dimension so one
-measurement covers the whole bucket; entries carry their provenance
-(``"measured"`` beats ``"model"`` — a model-seeded row never overwrites a
-measured one).
+The table persists to a versioned on-disk JSON file keyed by the
+**topology stamp** (jax backend, device kind, device count, XLA flags, jax
+version) with a crc32 over the canonical payload — a table written on a
+different topology, a different schema version, or a torn/corrupt file
+loads as an EMPTY table (clean miss, never a crash) and is simply rebuilt
+by the next sweep. Shapes are bucketed to the next power of two per
+dimension so one measurement covers the whole bucket.
 
 Installation is EXPLICIT: library code never reads the filesystem behind
 your back. Entry points (train/serve/service CLIs) call
@@ -50,10 +39,9 @@ import zlib
 
 import jax
 
-TABLE_VERSION = 1
+TABLE_VERSION = 2
 
-# every engine op the auto backend dispatches on; bench_kernels uses the
-# same keys so its records seed the table directly
+# every engine op the auto backend dispatches on
 OPS = ("norms", "clip_sum", "linear_clip", "scale_contract", "paged_attn")
 
 _BACKEND_CHOICES = ("xla", "pallas")
@@ -135,7 +123,7 @@ def bucket_key(op: str, t: int, din: int, dout: int) -> str:
 
 @dataclasses.dataclass
 class AutotuneTable:
-    """Bucketed (op, shape) -> {backend: {us, source}} timings for ONE
+    """Bucketed (op, shape) -> {backend: {us}} timings for ONE
     topology. `best()` is the measured argmin; buckets it has never seen
     return None so the caller falls back to the static model."""
 
@@ -145,39 +133,26 @@ class AutotuneTable:
     stale_reason: str | None = None  # why a load came back empty
 
     def record(self, op: str, t: int, din: int, dout: int, backend: str,
-               us: float, *, source: str = "measured") -> bool:
-        """Record one timing; measured entries always beat model-seeded
-        ones (a model estimate never overwrites a measurement). Returns
-        True if the entry was stored."""
+               us: float) -> None:
+        """Record one measured timing, replacing any earlier one for the
+        same bucket and backend."""
         if backend not in _BACKEND_CHOICES:
             raise ValueError(f"unknown backend {backend!r}; "
                              f"expected one of {_BACKEND_CHOICES}")
         if not (us > 0.0) or us != us or us == float("inf"):
             raise ValueError(f"bad timing {us!r} for {op}")
         key = bucket_key(op, t, din, dout)
-        slot = self.entries.setdefault(key, {})
-        prev = slot.get(backend)
-        if prev is not None and prev.get("source") == "measured" \
-                and source != "measured":
-            return False
-        slot[backend] = {"us": float(us), "source": source}
-        return True
+        self.entries.setdefault(key, {})[backend] = {"us": float(us)}
 
     def lookup(self, op: str, t: int, din: int, dout: int) -> dict | None:
         return self.entries.get(bucket_key(op, t, din, dout))
 
     def best(self, op: str, t: int, din: int, dout: int) -> str | None:
-        """Measured argmin for this bucket, or None if unmeasured.
-
-        Measured rows win outright; model-seeded rows only decide a bucket
-        with no measurements at all."""
+        """Measured argmin for this bucket, or None if unmeasured."""
         slot = self.lookup(op, t, din, dout)
         if not slot:
             return None
-        measured = {b: v for b, v in slot.items()
-                    if v.get("source") == "measured"}
-        pool = measured or slot
-        return min(pool, key=lambda b: pool[b]["us"])
+        return min(slot, key=lambda b: slot[b]["us"])
 
     def __len__(self) -> int:
         return len(self.entries)
@@ -280,8 +255,7 @@ def install_default(cache_root: str | None = None) -> AutotuneTable:
 
 @contextlib.contextmanager
 def use_table(table: AutotuneTable | None):
-    """Scope a table for the dynamic extent of the block (tests; also how
-    bench_kernels reports post-seeding auto choices)."""
+    """Scope a table for the dynamic extent of the block (tests)."""
     token = _OVERRIDE.set(table)
     try:
         yield table
@@ -293,8 +267,8 @@ def use_table(table: AutotuneTable | None):
 # The measured sweep.
 # ---------------------------------------------------------------------------
 
-# (B, T, din, dout) buckets worth measuring by default — the bench_kernels
-# grid plus the production-ish tails. Interpret-mode pallas off-TPU is
+# (B, T, din, dout) buckets worth measuring by default — a small grid plus
+# the production-ish tails. Interpret-mode pallas off-TPU is
 # minutes-slow above ~256²; the sweep caps itself unless forced.
 SWEEP_SHAPES_QUICK = ((4, 128, 128, 128), (4, 256, 256, 256))
 SWEEP_SHAPES_FULL = ((4, 512, 256, 256), (8, 1024, 512, 512),
@@ -341,8 +315,7 @@ def _op_data(op: str, shape):
 
 def paged_attn_data(shape, *, page_len: int = 16, kv: int = 2, grp: int = 2):
     """Decode-attention operands whose table key maps t -> logical context
-    and (din, dout) -> (query head dim, value head dim). Shared with
-    bench_kernels so seeding and lookup agree on the bucket."""
+    and (din, dout) -> (query head dim, value head dim)."""
     import jax.numpy as jnp
     b, t, din, dout = shape
     dq = min(din, 64)
@@ -422,29 +395,6 @@ def sweep(*, ops=OPS, shapes=None, table: AutotuneTable | None = None,
                 progress(op, shape, timings)
     if save:
         table.save()
-    return table
-
-
-def seed_from_records(records, table: AutotuneTable | None = None,
-                      *, source: str = "measured") -> AutotuneTable:
-    """Seed the table from bench_kernels-style records
-    ({name: kernel_<op>_<backend>, t, din, dout, us_per_call}). Rows with
-    no timing (skipped backends, naive baselines) are ignored."""
-    if table is None:
-        table = load()
-    for rec in records:
-        name = rec.get("name", "")
-        backend_name = rec.get("backend")
-        us = rec.get("us_per_call")
-        if backend_name not in _BACKEND_CHOICES or not us:
-            continue
-        if not name.startswith("kernel_"):
-            continue
-        op = name[len("kernel_"):-(len(backend_name) + 1)]
-        if op not in OPS:
-            continue
-        table.record(op, rec["t"], rec["din"], rec["dout"],
-                     backend_name, float(us), source=source)
     return table
 
 

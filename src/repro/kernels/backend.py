@@ -55,10 +55,9 @@ How `auto` chooses for a linear (B, T, din, dout):
 
 Engine config is SCOPED, not global: `with backend.scoped("pallas"): ...`
 pushes an engine for the dynamic extent of the block, so jitted step
-functions capture their backend statically at trace time (this replaces the
-old `ghost.configure()` module-global mutation). Unspecified fields inherit
-from the enclosing scope, so e.g. the dry-run can widen `outer_max_elems`
-and a `make_dp_train_step(cfg)` inside still honors it.
+functions capture their backend statically at trace time. Unspecified
+fields inherit from the enclosing scope, so e.g. the dry-run can widen
+`outer_max_elems` and a `make_dp_train_step(cfg)` inside still honors it.
 """
 from __future__ import annotations
 
@@ -130,8 +129,7 @@ class EngineConfig:
     """
 
     backend: str = "xla"
-    # xla path policy; None -> fall through to the repro.core.ghost module
-    # globals, so legacy ghost.configure() callers stay honored
+    # xla path policy; None -> the repro.core.ghost DEFAULT_* constants
     outer_max_elems: int | None = None
     gram_chunk: int | None = None
     # pallas tile sizes
@@ -357,8 +355,7 @@ def choose_linear_path(t: int, din: int, dout: int, config: EngineConfig,
     """The STATIC cost model's decision for one linear ghost op:
     'xla'|'pallas'. This is the fallback for shape buckets the autotune
     table has never measured (`choose_op` is the full decision); pure
-    function of static shapes + config, exposed for tests and for the
-    benchmark sweep to report what the model alone would pick.
+    function of static shapes + config, exposed for tests.
     """
     if on_tpu is None:
         on_tpu = jax.default_backend() == "tpu"
@@ -366,7 +363,8 @@ def choose_linear_path(t: int, din: int, dout: int, config: EngineConfig,
         # unmeasured + off-TPU: interpret-mode kernels are validation-only
         # (a MEASURED interpret-mode win is honored by choose_op above)
         return "xla"
-    outer_cap = (ghost._OUTER_MAX_ELEMS if config.outer_max_elems is None
+    outer_cap = (ghost.DEFAULT_OUTER_MAX_ELEMS
+                 if config.outer_max_elems is None
                  else config.outer_max_elems)
     outer_ok = din * dout <= outer_cap
     if outer_ok and (ghost.outer_path_cost(t, din, dout)

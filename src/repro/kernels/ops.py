@@ -3,8 +3,8 @@
 Kernel routing is owned by the backend engine (`repro.kernels.backend`):
 select it per training run with `DPConfig(backend="pallas" | "auto")` or
 scope it manually with `backend.scoped(...)` — there is no module-global
-switch. The wrappers here are thin jitted entry points for tests and
-benchmarks that want to hit one kernel directly.
+switch. The wrappers here are thin jitted entry points for callers that
+want to hit one kernel directly.
 
 On TPU the kernels compile through Mosaic; on CPU (this container) they run
 in interpret mode for correctness validation and the XLA reference paths

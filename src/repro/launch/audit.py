@@ -16,8 +16,8 @@ For every config in the matrix this driver builds the REAL train step
 (`make_dp_train_step`, tiny arch), runs BOTH static passes —
 `repro.analysis.jaxpr_taint` on the closed jaxpr and
 `repro.analysis.rules` on the compiled post-SPMD HLO — and aggregates
-the findings into benchmarks/AUDIT.json (stamped with the same topology
-record as the BENCH artifacts). Any ERROR finding exits non-zero: the
+the findings into benchmarks/AUDIT.json (stamped with the topology record
+that keys the autotune table). Any ERROR finding exits non-zero: the
 audit is a CI gate, not a report.
 
 The matrix pins `backend="xla"` like launch.dryrun: the fused Pallas

@@ -16,8 +16,8 @@ ShapeDtypeStruct inputs (no allocation), compiles, and extracts:
                          kind (all-reduce, all-gather, reduce-scatter,
                          all-to-all, collective-permute)
 
-Results go to benchmarks/results/dryrun/<arch>__<shape>__<mesh>.json, which
-benchmarks/roofline.py and EXPERIMENTS.md consume.
+Results go to benchmarks/results/dryrun/<arch>__<shape>__<mesh>.json
+(gitignored). No code in the repo reads them; they are for inspection.
 
 Usage:
   python -m repro.launch.dryrun --arch qwen3-4b --shape train_4k --mesh single
@@ -36,13 +36,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import optim
+from repro.analysis.hlo import analyze_hlo, backward_passes
 from repro.configs import ARCH_IDS, get_config
 from repro.core.clipping import tied_refusal
 from repro.core.dp_sgd import DPConfig, make_dp_train_step
 from repro.core.spec import abstract_params
 from repro.launch import inputs as I
 from repro.launch.mesh import make_production_mesh
-from repro.launch.hlo_analysis import analyze_hlo, backward_passes
 from repro.launch.sharding import (batch_shardings, cache_shardings,
                                    opt_state_shardings, params_shardings,
                                    replicated)
@@ -148,7 +148,7 @@ def build_train_lowering(arch: str, shape_name: str, mesh, *,
     assign, nsuper = None, None
     if clipping.startswith("per_group") and not sharded:
         # per-DEVICE supergroups from model-axis shard ownership — the SAME
-        # helper the sharded executing path and bench_sharded use (under
+        # helper the sharded executing path uses (under
         # `sharded` the factory derives this from the mesh itself)
         from repro.launch.sharding import group_shard_assignment
         nsuper = int(mesh.shape["model"])
@@ -351,9 +351,9 @@ def run_one(arch: str, shape_name: str, mesh_kind: str, *,
                        "num_errors": len(errors(fs))}
         axis_coll = None
         if sharded and kind == "train":
-            from repro.launch.hlo_analysis import (classify_collectives,
-                                                   filter_model_norm_rows,
-                                                   summarize_axis_rows)
+            from repro.analysis.hlo import (classify_collectives,
+                                            filter_model_norm_rows,
+                                            summarize_axis_rows)
             rows = classify_collectives(hlo, mesh)  # parse the HLO once
             axis_coll = {
                 "by_axis": summarize_axis_rows(rows),

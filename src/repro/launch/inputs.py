@@ -70,8 +70,8 @@ def pad_ragged_prompts(prompts) -> tuple[np.ndarray, np.ndarray]:
 def synthetic_requests(vocab_size: int, n: int, *, min_len: int,
                        max_len: int, seed: int = 0) -> list[np.ndarray]:
     """n random prompts with lengths uniform in [min_len, max_len] — the
-    ragged request sets used by the serve CLI, the engine smoke and
-    benchmarks/bench_serve.py."""
+    ragged request sets used by the serve CLI, the engine smoke and the
+    examples."""
     if not 1 <= min_len <= max_len:
         raise ValueError(f"need 1 <= min_len <= max_len, got "
                          f"[{min_len}, {max_len}]")

@@ -103,10 +103,10 @@ def group_shard_assignment(layout, model_size: int) -> tuple[int, ...]:
     This is what makes `per_group` clipping mean PER-DEVICE clipping (paper
     Sec 4): supergroup s = "everything shard s owns", so each shard's norm
     reductions and clip factors close over shard-local groups only. Shared
-    by `launch/train.py`, `launch/dryrun.py` and `benchmarks/bench_sharded`
-    so the CLI, the lowering sweep and the executing sharded engine all
-    agree on the partition. Ownership is derived from the SAME rule table
-    that places the parameters (`_RULES`):
+    by `launch/train.py` and `launch/dryrun.py` so the CLI, the lowering
+    sweep and the executing sharded engine all agree on the partition.
+    Ownership is derived from the SAME rule table that places the
+    parameters (`_RULES`):
 
       * blocked groups (`P.blocks == model_size`) whose weight is
         column-parallel: block j lives on shard j — exact Megatron

@@ -193,7 +193,7 @@ def jit_step(step_fn, model, mesh):
     if mesh is not None:
         # weights are STORED model-sharded between steps (memory: 1/M per
         # device); the shard_map entry all-gathers them — weight traffic,
-        # classified separately from norm traffic by hlo_analysis
+        # classified separately from norm traffic by analysis.hlo
         from repro.launch.sharding import params_shardings
         pshard = params_shardings(model.spec, mesh)
         return jax.jit(step_fn,

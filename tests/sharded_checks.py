@@ -18,7 +18,7 @@ Checks (sharded == single-device, same math different communication):
   * the DP-LoRA trainable_key path (ghost_flat on a reduced qwen3-4b);
   * the Sec-4 communication contract from compiled HLO: per-device
     (per_group) has ZERO model-axis collectives in norm computation,
-    ghost_flat has >= 1 (launch.hlo_analysis.model_axis_norm_collectives);
+    ghost_flat has >= 1 (analysis.hlo.model_axis_norm_collectives);
   * the quantile contract: shard-local clip counts psum'd over the data
     plane (quantile.update_thresholds counts_axes=) reproduce the
     single-device geometric update bit-for-bit on every shard;
@@ -40,11 +40,11 @@ import numpy as np
 from jax.sharding import PartitionSpec as PS
 
 from repro import optim
+from repro.analysis.hlo import model_axis_norm_collectives
 from repro.configs import get_config
 from repro.core.clipping import dp_clipped_gradients, sharded_clipped_gradients
 from repro.core.dp_sgd import DPConfig, make_dp_train_step
 from repro.core.spec import abstract_params, init_params
-from repro.launch.hlo_analysis import model_axis_norm_collectives
 from repro.launch.inputs import concrete_train_batch
 from repro.launch.mesh import make_debug_mesh, named_shard_map
 from repro.launch.sharding import group_shard_assignment

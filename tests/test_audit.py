@@ -230,13 +230,3 @@ def test_rule_catalog_is_closed():
     for rid, (sev, invariant) in RULES.items():
         assert sev in SEVERITIES and invariant
     assert set(audit_mod.MUTATIONS.values()) <= set(RULES)
-
-
-def test_hlo_analysis_shim_reexports():
-    # satellite (a): launch.hlo_analysis moved to analysis.hlo; the shim
-    # must keep every public name importable for older callers
-    from repro.launch import hlo_analysis as shim
-    for name in ("analyze_hlo", "backward_passes", "classify_collectives",
-                 "filter_model_norm_rows", "entry_aliases",
-                 "dynamic_shape_instrs", "HloAnalyzer", "Totals"):
-        assert getattr(shim, name) is getattr(hlo_mod, name), name

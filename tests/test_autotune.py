@@ -1,6 +1,6 @@
 """Measured kernel autotuning (repro.kernels.autotune): table persistence
 discipline (versioned, topology-stamped, checksummed, never crashes on a
-bad file), measured-beats-model precedence, and the acceptance bar — the
+bad file), record/argmin semantics, and the acceptance bar — the
 auto backend's choice equals the measured argmin on every measured bucket,
 on any jax backend, with the static model only deciding unmeasured ones."""
 import json
@@ -45,31 +45,13 @@ def test_bucket_key_covers_the_bucket():
 
 def test_record_and_best_argmin():
     tab = _tab()
-    assert tab.record("norms", 128, 64, 64, "xla", 100.0)
-    assert tab.record("norms", 128, 64, 64, "pallas", 50.0)
+    tab.record("norms", 128, 64, 64, "xla", 100.0)
+    tab.record("norms", 128, 64, 64, "pallas", 50.0)
     assert tab.best("norms", 128, 64, 64) == "pallas"
     assert tab.best("norms", 4096, 64, 64) is None  # unmeasured bucket
     # refreshing a measurement updates it
     tab.record("norms", 128, 64, 64, "pallas", 500.0)
     assert tab.best("norms", 128, 64, 64) == "xla"
-
-
-def test_measured_beats_model_seed():
-    tab = _tab()
-    tab.record("clip_sum", 128, 64, 64, "xla", 100.0)
-    # a model estimate must never overwrite a measurement...
-    assert not tab.record("clip_sum", 128, 64, 64, "xla", 1.0,
-                          source="model")
-    assert tab.lookup("clip_sum", 128, 64, 64)["xla"]["us"] == 100.0
-    # ...and a model-only row never outvotes a measured one in best()
-    tab.record("clip_sum", 128, 64, 64, "pallas", 0.5, source="model")
-    assert tab.best("clip_sum", 128, 64, 64) == "xla"
-    # but a bucket with ONLY model rows still resolves
-    tab.record("clip_sum", 512, 64, 64, "pallas", 2.0, source="model")
-    assert tab.best("clip_sum", 512, 64, 64) == "pallas"
-    # and a later measurement takes the bucket over
-    tab.record("clip_sum", 128, 64, 64, "pallas", 10.0)
-    assert tab.best("clip_sum", 128, 64, 64) == "pallas"
 
 
 def test_record_rejects_garbage():
@@ -287,25 +269,8 @@ def test_auto_paged_impl_hint_consults_table():
 
 
 # ---------------------------------------------------------------------------
-# Seeding paths: bench records + the live sweep.
+# The live sweep.
 # ---------------------------------------------------------------------------
-
-
-def test_seed_from_records_parses_bench_rows():
-    records = [
-        {"name": "kernel_clip_sum_pallas", "backend": "pallas",
-         "t": 128, "din": 64, "dout": 64, "us_per_call": 5.0},
-        {"name": "kernel_clip_sum_xla", "backend": "xla",
-         "t": 128, "din": 64, "dout": 64, "us_per_call": 9.0},
-        {"name": "kernel_norms_naive", "backend": "naive",
-         "t": 128, "din": 64, "dout": 64, "us_per_call": 3.0},  # ignored
-        {"name": "kernel_pallas_skipped", "backend": "pallas"},  # no timing
-        {"name": "other_row", "backend": "xla", "t": 1, "din": 1,
-         "dout": 1, "us_per_call": 1.0},  # not a kernel row
-    ]
-    tab = autotune.seed_from_records(records, _tab())
-    assert len(tab) == 1
-    assert tab.best("clip_sum", 128, 64, 64) == "pallas"
 
 
 def test_sweep_measures_and_persists(tmp_path):
@@ -314,8 +279,7 @@ def test_sweep_measures_and_persists(tmp_path):
                          table=_tab(path))
     slot = tab.lookup("norms", 128, 64, 64)
     assert set(slot) == {"xla", "pallas"}
-    assert all(v["us"] > 0 and v["source"] == "measured"
-               for v in slot.values())
+    assert all(v["us"] > 0 for v in slot.values())
     back = autotune.load(path)
     assert back.entries == tab.entries
     assert back.best("norms", 128, 64, 64) in ("xla", "pallas")

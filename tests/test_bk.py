@@ -6,7 +6,7 @@ Contract under test (repro.core.bk + kernels/clip_reduce.scale_contract):
     DP-LoRA trainable_key path;
   * the scale_contract Pallas kernel matches its jnp oracle;
   * the compiled HLO really contains ONE backward pass under execution=bk
-    and TWO under twopass (launch.hlo_analysis.backward_passes);
+    and TWO under twopass (analysis.hlo.backward_passes);
   * unsupported layouts (shared-site params) fall back to twopass;
   * naive_flat reports real per-layout-group norms².
 """
@@ -256,7 +256,7 @@ def test_scale_contract_backend_op_parity():
 
 @pytest.mark.slow
 def test_hlo_reports_single_backward_pass_under_bk():
-    from repro.launch.hlo_analysis import backward_passes
+    from repro.analysis.hlo import backward_passes
     cfg = dataclasses.replace(get_config("tiny"), num_layers=4)
     m = build_model(cfg)
     params = abstract_params(m.spec)

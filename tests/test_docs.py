@@ -1,6 +1,6 @@
 """Doc drift: the docs are checked against the code, mechanically.
 
-Three contracts:
+Four contracts:
 
   * every CLI flag of the train / serve / service / audit parsers is
     documented somewhere in README.md or docs/, and every flag-looking
@@ -9,8 +9,12 @@ Three contracts:
   * every relative markdown link resolves to a real file, and every
     `#anchor` to a real heading in its target;
   * the engine-stats table in docs/serving.md is byte-identical to what
-    `DecodeEngine.STATS_DOC` renders — the field list cannot rot.
+    `DecodeEngine.STATS_DOC` renders — the field list cannot rot;
+  * every `python -m <module>` the docs and scripts/ci.sh name resolves,
+    and every repo-relative path they name exists — a deleted module or
+    script cannot linger in a command.
 """
+import importlib.util
 import os
 import re
 
@@ -28,11 +32,10 @@ DOC_FILES = ["README.md", "docs/architecture.md", "docs/serving.md",
              "docs/operations.md"]
 
 # flag-looking tokens the docs legitimately mention that belong to OTHER
-# CLIs (autotune sweep, benchmarks, dryrun) or to env-var examples —
+# CLIs (autotune sweep, dryrun, chip_smoke) or to env-var examples —
 # anything else undocumented-in-a-parser is treated as stale
 OTHER_CLI_FLAGS = {
     "--sweep", "--show", "--full",          # repro.kernels.autotune
-    "--smoke",                              # benchmarks.* smoke modes
     "--shape", "--audit",                   # repro.launch.dryrun
     "--chips",                              # chip_smoke.py
 }
@@ -129,6 +132,37 @@ def test_markdown_links_resolve(doc):
                 if frag not in _anchors(fh.read()):
                     problems.append(f"{target}: no heading for #{frag}")
     assert not problems, f"{doc}: broken links: {problems}"
+
+
+# --------------------------------------------------------------------------
+# module and path references
+# --------------------------------------------------------------------------
+
+_MODULE = re.compile(r"\bpython3? -m ([A-Za-z_][\w.]*)")
+_PATH = re.compile(r"(?<![\w./-])(?:\./)?"
+                   r"((?:scripts|benchmarks|bench|examples|tests|docs|src)"
+                   r"/[\w./-]*)")
+
+
+def _resolves(module):
+    try:
+        return importlib.util.find_spec(module) is not None
+    except ModuleNotFoundError:  # a parent package is missing
+        return False
+
+
+@pytest.mark.parametrize("doc", DOC_FILES + ["scripts/ci.sh"])
+def test_named_modules_and_paths_exist(doc, monkeypatch):
+    text = _read(doc)
+    monkeypatch.syspath_prepend(ROOT)
+    monkeypatch.syspath_prepend(os.path.join(ROOT, "src"))
+    missing = sorted({m for m in _MODULE.findall(text) if not _resolves(m)})
+    paths = {p.rstrip(".") for p in _PATH.findall(text)}  # sentence ends
+    missing += sorted(p for p in paths
+                      if not os.path.exists(os.path.join(ROOT, p)))
+    assert not missing, (
+        f"{doc} names modules or paths that do not exist (deleted or "
+        f"renamed?): {missing}")
 
 
 # --------------------------------------------------------------------------

@@ -12,8 +12,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.analysis.hlo import analyze_hlo
 from repro.configs import get_config
-from repro.launch.hlo_analysis import analyze_hlo
 from repro.launch.inputs import serve_batch_specs, train_batch_specs
 from repro.models.config import INPUT_SHAPES
 
@@ -36,7 +36,7 @@ def test_hlo_analyzer_scan_trip_counts():
 
 
 def test_hlo_analyzer_tuple_shapes_with_index_comments():
-    from repro.launch.hlo_analysis import _parse_instr_line
+    from repro.analysis.hlo import _parse_instr_line
     line = ('  %while.1 = (s32[], f32[36,64]{1,0}, /*index=5*/f32[2,3]) '
             'while(%tuple.1), condition=%c, body=%b, '
             'backend_config={"known_trip_count":{"n":"7"}}')

@@ -17,9 +17,8 @@ import sys
 import numpy as np
 import pytest
 
+from repro.analysis.hlo import _axes_of_groups, _parse_replica_groups
 from repro.core.spec import GroupLayout, P
-from repro.launch.hlo_analysis import (_axes_of_groups,
-                                       _parse_replica_groups)
 from repro.launch.sharding import group_shard_assignment
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
@@ -105,7 +104,7 @@ def test_axes_of_groups_classification():
 
 
 def test_classify_collectives_from_synthetic_hlo():
-    from repro.launch.hlo_analysis import classify_collectives
+    from repro.analysis.hlo import classify_collectives
 
     class FakeDev:
         def __init__(self, i):
