@@ -48,9 +48,10 @@ How `auto` chooses for a linear (B, T, din, dout):
      for this (op, shape bucket) -> the measured argmin backend;
   1. outer path allowed (din·dout <= outer_max_elems) and cheaper by flops
      -> xla outer path (one einsum, no kernel beats it);
-  2. else gram regime: T >= bt and the kernel's working set
-     (4·bt·dk + 2·bt²) f32 fits vmem_limit_bytes -> pallas gram kernel
-     (the (B,T,T) gram never touches HBM);
+  2. else gram regime: T >= bt and the VMEM of the norm kernel's own
+     tiles (`ghost_norm.tiles`, an explicit `dk` included, f32 operands)
+     fits what it may ask Mosaic for (`ghost_norm.VMEM_BUDGET`) -> pallas
+     gram kernel (the (B,T,T) gram never touches HBM);
   3. else -> xla gram/gram_chunked.
 
 Engine config is SCOPED, not global: `with backend.scoped("pallas"): ...`
@@ -75,7 +76,9 @@ from repro.kernels.clip_reduce import clip_reduce
 from repro.kernels.clip_reduce import scale_contract as scale_contract_kernel
 from repro.kernels.fused_clip import fused_norm_clip
 from repro.kernels.fused_clip import padded_dims as fused_clip_padded_dims
+from repro.kernels.ghost_norm import VMEM_BUDGET as GHOST_NORM_VMEM
 from repro.kernels.ghost_norm import ghost_norm, ghost_norm_blocked
+from repro.kernels.ghost_norm import vmem_bytes as ghost_norm_vmem
 from repro.kernels.paged_attn import paged_attn as paged_attn_kernel
 from repro.kernels.ref import paged_attn_ref
 
@@ -101,10 +104,14 @@ class EngineConfig:
       elements for the outer-product norms path, and the gram-matrix
       chunk size (elements along B·T). `None` inherits the
       `repro.core.ghost` module defaults.
-    * `bt`, `dk`: pallas tile sizes of `ghost_norm` and the fused kernel
-      (rows of the sequence and feature chunk; units = array elements).
-      Defaults suit ~16 MB VMEM cores; the autotune sweep measures
-      alternatives.
+    * `bt`: the sequence tile of the fused kernel and of
+      `ghost_norm_blocked` (rows; default 256), and `auto`'s shortest
+      sequence for the gram kernel. `ghost_norm` derives its own row
+      blocks and sub-tiles from the shape (`ghost_norm.tiles`).
+    * `dk`: the feature chunk of `ghost_norm` (and of
+      `ghost_norm_blocked`, 512 when unset). `None` (the default) means
+      derived: `ghost_norm.tiles` sizes A's and G's chunks from the shape
+      and dtype. An explicit value overrides both.
     * `bi`, `bj`: the din and dout tiles of `clip_reduce` and
       `scale_contract`, which share one kernel. `None` (the default)
       means derived: `clip_reduce.tiles` sizes both from the shape and
@@ -112,8 +119,10 @@ class EngineConfig:
       derived, since it has to divide the (padded) sequence.
     * `interpret` (default `None` = interpret off-TPU, compiled on
       TPU): force pallas interpret mode either way.
-    * `vmem_limit_bytes` (default 12 MiB): kernel-selection guard —
-      `auto` rejects a pallas candidate whose working set exceeds it.
+    * `vmem_limit_bytes` (default 12 MiB): the fused kernel's VMEM guard
+      — its working set must fit, else `linear_clip` composes the norm
+      and clipped-sum kernels. The other kernels size their tiles to
+      what they ask Mosaic for.
     * `prefer_fused` (default True): allow the single-pallas_call fused
       norm+clip kernel; scoped off by the two-pass drivers so the
       norms-only pass can dead-code-eliminate the unused contraction.
@@ -133,8 +142,8 @@ class EngineConfig:
     outer_max_elems: int | None = None
     gram_chunk: int | None = None
     # pallas tile sizes
-    bt: int = 256   # sequence tile (ghost_norm / fused)
-    dk: int = 512   # feature-chunk tile (ghost_norm)
+    bt: int = 256   # sequence tile (fused, ghost_norm_blocked)
+    dk: int | None = None   # ghost_norm feature chunk; None -> derived
     bi: int | None = None   # clip_reduce / scale_contract din tile
     bj: int | None = None   # clip_reduce / scale_contract dout tile
     # None -> interpret off TPU, compiled on TPU; bools force it
@@ -298,7 +307,7 @@ class PallasBackend(Backend):
 
     def linear_norms_sq(self, a, g):
         a3, g3 = ghost._as3d(a), ghost._as3d(g)
-        return ghost_norm(a3, g3, bt=self.config.bt, dk=self.config.dk,
+        return ghost_norm(a3, g3, dk=self.config.dk,
                           interpret=self._interpret())
 
     def linear_norms_sq_blocked(self, a, g, num_blocks, *, block_axis="out"):
@@ -371,9 +380,10 @@ def choose_linear_path(t: int, din: int, dout: int, config: EngineConfig,
                      < ghost.gram_path_cost(t, din, dout)):
         return "xla"  # one einsum, transient fits: nothing to fuse
     if t < config.bt:
-        return "xla"  # sub-tile sequence: kernel grid degenerates
-    working_set = 4 * (4 * config.bt * config.dk + 2 * config.bt * config.bt)
-    if working_set > config.vmem_limit_bytes:
+        return "xla"  # a sequence shorter than one row tile
+    # the kernel's own tiles, sized for f32 operands (bf16 need less)
+    if ghost_norm_vmem(t, din, dout, jnp.float32,
+                       dk=config.dk) > GHOST_NORM_VMEM:
         return "xla"
     return "pallas"
 

@@ -32,7 +32,7 @@ def _interpret() -> bool:
 
 
 @partial(jax.jit, static_argnames=("bt", "dk"))
-def ghost_norm_op(a, g, *, bt: int = 256, dk: int = 512):
+def ghost_norm_op(a, g, *, bt: int | None = None, dk: int | None = None):
     return ghost_norm(a, g, bt=bt, dk=dk, interpret=_interpret())
 
 

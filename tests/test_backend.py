@@ -88,6 +88,38 @@ def test_choose_linear_path_cost_model():
                                       on_tpu=False) == "pallas"
 
 
+def _cell_linears():
+    """(T, din, dout) of every matmul of every benchmark cell, from its
+    configuration's `bench/flops/dense.py` linears, named cell.linear."""
+    import json
+    import pathlib
+
+    from bench.flops import dense
+    root = pathlib.Path(__file__).resolve().parents[1]
+    spec = json.loads((root / "BENCHMARK.json").read_text())
+    files = {c["name"]: c["file"] for c in spec["configs"]}
+    for cell in spec["workloads"]:
+        cfg = json.loads((root / files[cell["config"]]).read_text())
+        traffic = root / "bench" / "traffic" / f"{cell['traffic']}.json"
+        t = json.loads(traffic.read_text())["seq"]
+        for name, din, dout, _ in dense.linears(cfg):
+            yield pytest.param(t, din, dout, id=f"{cell['name']}.{name}")
+
+
+@pytest.mark.parametrize("t,din,dout", list(_cell_linears()))
+def test_auto_routes_cell_linears_to_pallas(t, din, dout):
+    """On the chip, with no measured table, `auto` sends every linear of
+    the benchmark's cells to the Pallas kernels, the norm kernel's derived
+    tiles checked against its VMEM: `ghost_norm_roofline.train` counts
+    exactly these linears."""
+    cfg = backend.EngineConfig(backend="auto", autotune=False)
+    assert backend.choose_linear_path(t, din, dout, cfg,
+                                      on_tpu=True) == "pallas"
+    for op in ("norms", "linear_clip"):
+        assert backend.choose_op(op, t, din, dout, cfg,
+                                 on_tpu=True) == "pallas"
+
+
 # ---------------------------------------------------------------------------
 # Op-level parity: pallas (interpret) ≡ xla for the full op surface.
 # ---------------------------------------------------------------------------

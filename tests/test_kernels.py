@@ -37,6 +37,37 @@ def test_ghost_norm_kernel(shape, dtype):
     np.testing.assert_allclose(got, want, rtol=rtol)
 
 
+# ghost_norm's derived tiles (no bt or dk given), each case against the f32
+# oracle in both dtypes:
+#   T = 64, one block of one sub-tile: the diagonal-only path;
+#   T = 512, one block: G's width 4099 (odd, as the heads' are) in three
+#     chunks with a ragged last, A's 130 in one, din != dout;
+#   T = 1100, one block of whole sub-tiles reaching past T (none divides
+#     it): masked rows, and G's 2100 in two ragged chunks;
+#   T = 2100, two blocks: the pair walk with an off-diagonal pair and a
+#     ragged last block.
+DERIVED_SHAPES = [
+    (2, 64, 48, 40),
+    (2, 512, 130, 4099),
+    (1, 1100, 200, 2100),
+    (1, 2100, 40, 300),
+]
+
+
+@pytest.mark.parametrize("shape", DERIVED_SHAPES)
+@pytest.mark.parametrize("dtype", DTYPES)
+def test_ghost_norm_derived_tiles(shape, dtype):
+    b, t, din, dout = shape
+    key = jax.random.PRNGKey(t)
+    a = jax.random.normal(key, (b, t, din)).astype(dtype)
+    g = (jax.random.normal(jax.random.fold_in(key, 1), (b, t, dout)) * 0.1
+         ).astype(dtype)
+    got = ghost_norm(a, g, interpret=True)
+    want = ref.ghost_norm_ref(a, g)
+    rtol = 4e-3 if dtype == jnp.bfloat16 else 1e-4
+    np.testing.assert_allclose(got, want, rtol=rtol)
+
+
 # clip_reduce past SHAPES: din 200 (not a multiple of 128) with a head-like
 # dout of 128·13, and din 384 (128·3) with dout 130: no derived or explicit
 # tile divides them, so the last blocks are ragged; T = 40 and 100 are no

@@ -114,6 +114,33 @@ def test_kernel_compiles_for_v5e_at_qwen3_4b_widths(name, one_chip):
     assert any(f"{kernel}/pallas_call" in ln for ln in kernel_lines)
 
 
+@pytest.mark.parametrize("b,t,din,dout", [(2, 2048, MC_D, MC_VOCAB),
+                                          (B, T, D, VOCAB)],
+                         ids=["minicpm_head", "qwen3_head"])
+def test_ghost_norm_at_cell_heads_takes_bf16_unpadded(b, t, din, dout,
+                                                      one_chip):
+    """`ghost_norm` at the largest shape of each cell, the tied MiniCPM
+    head's and the untied qwen3 head's, compiles for a described v5e as
+    one pallas_call that takes the bf16 activations and gradients as they
+    are: no pad of either, and T in one block (the pair table holds one
+    pair, so the j-side operands are absent)."""
+    import re
+
+    args = [jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
+            for s in ((b, t, din), (b, t, dout))]
+    text = jax.jit(lambda a, g: ghost_norm(a, g)).lower(
+        *args).compile().as_text()
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 1 and "ghost_norm/pallas_call" in calls[0]
+    operands = re.search(r"operand_layout_constraints=\{(.*?)\}\}",
+                         calls[0]).group(1)
+    assert re.findall(r"(\w+)\[([\d,]+)\]", operands) == [
+        ("s32", "4,1"), ("bf16", f"{b},{t},{din}"),
+        ("bf16", f"{b},{t},{dout}")], operands
+    assert not re.search(r"= \S+ pad\(", text)
+
+
 def test_tiny_dp_step_phases_for_v5e(one_chip):
     """The phase map on the TPU compiler's instruction names: every op of
     a compiled private step gets one phase, every `ghost_norm` and
